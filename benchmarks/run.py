@@ -69,6 +69,8 @@ def main(argv=None) -> None:
                          "benchmark drift breaks CI instead of rotting")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = []
     fd.run(rows, smoke=args.smoke)
     sem.run(rows, smoke=args.smoke)

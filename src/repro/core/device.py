@@ -177,13 +177,21 @@ def default_device(backend: str, interpret: bool | None = None) -> Device:
         return dev
 
 
-def fit_block(block: int, n: int) -> int:
-    """Largest divisor of ``n`` that is <= ``block`` (blocks must tile exactly)."""
+def fit_block(block: int, n: int, multiple: int = 1) -> int:
+    """Largest divisor of ``n`` that is <= ``block`` (blocks must tile exactly).
+    With ``multiple``, the largest such divisor that is also a multiple of it
+    when one exists (a TPU lane axis wants 128), else the plain fit."""
     if n <= 0:
         raise ValueError(f"fit_block: cannot tile a dimension of size {n}")
     if block <= 0:
         raise ValueError(f"fit_block: block must be positive, got {block}")
     block = min(int(block), int(n))
+    if multiple > 1 and block < n:
+        b = block - block % multiple
+        while b >= multiple:
+            if n % b == 0:
+                return b
+            b -= multiple
     while n % block:
         block -= 1
     return block

@@ -49,16 +49,15 @@ Keyword mapping (paper appendix tables → this module):
                                under ``ctx.reduce_first(d)`` and read-modify-
                                write (first-visit contents are undefined on a
                                real TPU, zero-filled on jnp/loops/interpret).
-                               Real-TPU caveat: when an ACCUMULATED axis is
-                               outer to a slot axis (flash-bwd's dk/dv), the
-                               block's revisits are non-consecutive and rely
-                               on the compiled pipeline writing back and
-                               refetching the output window between them —
-                               guaranteed on jnp/loops/interpret, flagged for
-                               real-TPU validation in ROADMAP before compiled
-                               use (consecutive revisits — the accumulated
-                               axis innermost, as in dq or matmul — are the
-                               long-validated safe pattern everywhere)
+                               Real-TPU rule: when an ACCUMULATED axis is
+                               outer to a slot axis, the block's revisits
+                               are non-consecutive. jnp/loops/interpret
+                               keep the contents; a compiled TPU pipeline
+                               writes the block back when its index changes
+                               and never re-reads it, so the compiled
+                               pallas expansion REFUSES such outputs (the
+                               accumulated axis innermost, as in dq or
+                               matmul, is the portable pattern)
   dynamic input tiles          run-time data read by bodies and predicates
   (run-time kernel args /      without recompiling: a WHOLE-ARRAY input tile
   indirection arrays — the     (``block=None``) is visible to every grid cell
@@ -123,6 +122,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 from types import SimpleNamespace
 from typing import Callable, Sequence
 
@@ -143,6 +143,7 @@ __all__ = [
     "cdiv",
     "defines_namespace",
     "expand",
+    "kernel_names",
     "BACKENDS",
 ]
 
@@ -696,23 +697,45 @@ class Ctx:
 # The gather is one static-index ``jnp.take`` per haloed axis on the host
 # side of the call; its cost is the halo amplification ``(b + 2r) / b`` the
 # static cost model charges for the tile.
+#
+# On the compiled TPU expansion a block's last two dims must be multiples of
+# (sublane, 128) or span the whole array, which a ``b + 2r`` window never
+# does. There (``align=True``) the windows along the trailing two haloed axes
+# are widened to the next legal size; the extra elements continue the
+# window's wrap/clamp pattern and the body still sees exactly its
+# ``b + 2r`` window (a view of the fetched block).
 
-def _halo_axis_index(nblocks: int, b: int, r: int, s: int, wrap: bool):
+_LANES = 128
+
+
+def _sublanes(dtype) -> int:
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _halo_axis_index(nblocks: int, b: int, r: int, s: int, wrap: bool,
+                     width: int):
     """Static source indices for one haloed axis's windowed layout."""
-    offs = np.arange(-r, b + r)
+    offs = np.arange(-r, width - r)
     idx = (np.arange(nblocks)[:, None] * b + offs[None, :]).reshape(-1)
     return idx % s if wrap else np.clip(idx, 0, s - 1)
 
 
-def _lower_halo_tile(tile: Tile) -> tuple[Tile, Callable]:
+def _lower_halo_tile(tile: Tile, align: bool = False) -> tuple[Tile, Callable]:
     blk = tile.resolved_block()
     halo = tile.resolved_halo()
     nb = tuple(s // b for s, b in zip(tile.shape, blk))
-    wblk = tile.body_block()
+    wblk = list(tile.body_block())
+    if align:
+        nd = len(wblk)
+        for d, unit in ((nd - 2, _sublanes(tile.dtype)), (nd - 1, _LANES)):
+            if d >= 0 and halo[d]:
+                wblk[d] = cdiv(wblk[d], unit) * unit
+    wblk = tuple(wblk)
     wshape = tuple(n * w for n, w in zip(nb, wblk))
-    takes = [(d, jnp.asarray(_halo_axis_index(n, b, r, s, tile.wrap),
+    takes = [(d, jnp.asarray(_halo_axis_index(n, b, r, s, tile.wrap, w),
                              dtype=jnp.int32))
-             for d, (n, b, r, s) in enumerate(zip(nb, blk, halo, tile.shape))
+             for d, (n, b, r, s, w) in enumerate(
+                 zip(nb, blk, halo, tile.shape, wblk))
              if r]
 
     def windowize(arr):
@@ -725,21 +748,28 @@ def _lower_halo_tile(tile: Tile) -> tuple[Tile, Callable]:
     return lowered, windowize
 
 
-def _lower_halos(spec: Spec) -> tuple[Spec, list | None]:
-    """(lowered spec, per-input window fns) — (spec, None) when halo-free."""
+def _lower_halos(spec: Spec, align: bool = False
+                 ) -> tuple[Spec, list | None, list | None]:
+    """(lowered spec, per-input window fns, per-input body windows) —
+    (spec, None, None) when halo-free. A body window is the ``b + 2r`` shape
+    the body sees where the fetched block is wider (``align``), else None."""
     if not any(t.halo is not None and any(t.resolved_halo())
                for t in spec.inputs):
-        return spec, None
-    preps, inputs = [], []
+        return spec, None, None
+    preps, inputs, views = [], [], []
     for t in spec.inputs:
+        view = None
         if t.halo is not None and any(t.resolved_halo()):
-            lowered, prep = _lower_halo_tile(t)
+            lowered, prep = _lower_halo_tile(t, align)
+            if lowered.block != t.body_block():
+                view = t.body_block()
         else:
             lowered, prep = t, None
         inputs.append(lowered)
         preps.append(prep)
+        views.append(view)
     lowered = dataclasses.replace(spec, inputs=inputs)
-    return lowered, preps
+    return lowered, preps, views
 
 
 # ---------------------------------------------------------------------------
@@ -988,41 +1018,106 @@ def _expand_loops(spec: Spec, defines: SimpleNamespace):
     return fn
 
 
-def _expand_pallas(spec: Spec, defines: SimpleNamespace, interpret: bool):
+def _is_scalar_tile(t: Tile) -> bool:
+    """An integer input whose block is all ones: every grid cell reads ONE
+    element (a length, an offset, a block-table entry)."""
+    return (jnp.issubdtype(jnp.dtype(t.dtype), jnp.integer)
+            and all(b == 1 for b in t.resolved_block()))
+
+
+class _ScalarView:
+    """The body's view of a scalar tile on the pallas expansion: the whole
+    array lives in SMEM (scalar prefetch), and the body's read of its
+    all-ones block becomes the element the cell's index map names — a
+    scalar for ``ref[0, ..., 0]``, the (1, ..., 1) block broadcast from it
+    for any other index."""
+
+    __slots__ = ("_ref", "_index", "_gids", "_ndim")
+
+    def __init__(self, ref, index, gids, ndim):
+        self._ref, self._index, self._gids = ref, index, gids
+        self._ndim = ndim
+
+    def __getitem__(self, idx):
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        val = self._ref[tuple(self._index(*self._gids))]
+        if len(idx) == self._ndim and all(isinstance(i, int) for i in idx):
+            return val
+        return jnp.full((1,) * self._ndim, val)[idx]
+
+
+def _noncontiguous_revisits(spec: Spec, t: Tile) -> bool:
+    """True when an output accumulates over a reduce axis OUTER to one of
+    its slot axes: its block is then revisited on non-consecutive grid
+    steps."""
+    acc = spec.output_reduce_axes(t)
+    return any(a < s_ for a in acc for s_ in spec.output_slot_axes(t))
+
+
+def _expand_pallas(spec: Spec, defines: SimpleNamespace, interpret: bool,
+                   views=None):
     grid = spec.grid
     ng = len(grid)
-    n_in, n_out = len(spec.inputs), len(spec.outputs)
+    n_out = len(spec.outputs)
+    if not interpret:
+        # a compiled TPU pipeline writes an output block back when its index
+        # changes and never reads it again: accumulating across
+        # non-consecutive visits would silently read a stale buffer
+        bad = [t.name for t in spec.outputs
+               if _noncontiguous_revisits(spec, t)]
+        if bad:
+            raise ValueError(
+                f"kernel {spec.name!r}: outputs {bad} accumulate over a "
+                "reduce axis outer to their slot axes — their blocks are "
+                "revisited non-consecutively, which the compiled Pallas "
+                "pipeline does not support (split the kernel so each output "
+                "accumulates over its innermost axes)")
     tiles = {t.name: t for t in spec.inputs}
-    # index_tile tables, in first-use order (deduped): each is ALSO a regular
-    # input (the body's view is backend-identical), but its array is
-    # additionally prepended to the call as a scalar-prefetch operand whose
-    # SMEM ref the wrapped index maps read (PrefetchScalarGridSpec appends
-    # the scalar refs to every index map's grid ids).
-    table_names: list[str] = []
+    # Scalar tiles (integer, all-ones block: lengths, offsets, block tables)
+    # travel as scalar-prefetch operands in SMEM — a (1, 1) VMEM block would
+    # break the TPU's (8, 128) block rule — and index_tile tables are always
+    # scalar tiles, so the wrapped index maps read them from the same refs.
+    scalars = [t.name for t in spec.inputs if _is_scalar_tile(t)]
     for t in spec.inputs:
-        if t.index_tile is not None and t.index_tile[0] not in table_names:
-            table_names.append(t.index_tile[0])
-    n_tab = len(table_names)
-    table_pos = [next(i for i, t in enumerate(spec.inputs) if t.name == nm)
-                 for nm in table_names]
+        if t.index_tile is not None and t.index_tile[0] not in scalars:
+            raise ValueError(
+                f"kernel {spec.name!r}: index_tile table "
+                f"{t.index_tile[0]!r} must be an integer tile with an "
+                "all-ones block")
+    n_sc = len(scalars)
+    blocked = [t for t in spec.inputs if t.name not in scalars]
+    views = list(views) if views is not None else [None] * len(spec.inputs)
 
     def body_adapter(*refs):
-        refs = refs[n_tab:]  # drop the scalar-prefetch refs: the tables
-        gids = tuple(pl.program_id(d) for d in range(ng))  # arrive again as
-        scr = refs[n_in + n_out:]                          # regular inputs
+        srefs, refs = refs[:n_sc], refs[n_sc:]
+        gids = tuple(pl.program_id(d) for d in range(ng))
+        vmem_in = iter(refs[:len(blocked)])
+        ins = []
+        for t, view in zip(spec.inputs, views):
+            if t.name in scalars:
+                ins.append(_ScalarView(srefs[scalars.index(t.name)],
+                                       t.resolved_index(grid), gids,
+                                       len(t.shape)))
+                continue
+            ref = next(vmem_in)
+            if view is not None:   # aligned halo block: the body's window
+                ref = ref.at[tuple(pl.ds(0, w) for w in view)]
+            ins.append(ref)
+        outs = refs[len(blocked): len(blocked) + n_out]
+        scr = refs[len(blocked) + n_out:]
         ctx = Ctx("pallas", defines, gids, grid,
                   reduce_axes=spec.reduce_axes, scratch=scr)
-        spec.body(ctx, *refs[: n_in + n_out])
+        spec.body(ctx, *ins, *outs)
 
     def mk_index(t: Tile):
         base = t.resolved_index(grid)
         if t.index_tile is None:
-            if not n_tab:
+            if not n_sc:
                 return base
             return lambda *a: base(*a[:ng])
         tname, axis = t.index_tile
         tindex = tiles[tname].resolved_index(grid)
-        ti = table_names.index(tname)
+        ti = scalars.index(tname)
         nb = t.shape[axis] // t.resolved_block()[axis]
 
         def gather(*a):
@@ -1044,55 +1139,52 @@ def _expand_pallas(spec: Spec, defines: SimpleNamespace, interpret: bool):
     # interpreter ignores compiler params, so only pass them when compiling.
     kwargs = {}
     if not interpret:
-        sem = spec.resolved_semantics()
-        params_cls = getattr(pltpu, "CompilerParams", None) or \
-            getattr(pltpu, "TPUCompilerParams", None)
-        if params_cls is not None:
-            kwargs["compiler_params"] = params_cls(dimension_semantics=sem)
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=spec.resolved_semantics())
 
-    in_specs = [mk_block(t) for t in spec.inputs]
+    in_specs = [mk_block(t) for t in blocked]
     out_specs = [mk_block(t) for t in spec.outputs]
     out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in spec.outputs]
     scratch_shapes = [pltpu.VMEM(s.shape, s.dtype) for s in spec.scratch]
 
-    if n_tab:
-        call = pl.pallas_call(
-            body_adapter,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=n_tab, grid=grid,
-                in_specs=in_specs, out_specs=out_specs,
-                scratch_shapes=scratch_shapes),
-            out_shape=out_shape,
-            interpret=interpret,
-            **kwargs,
-        )
-
-        def fn(*in_arrays):
-            tabs = [in_arrays[i] for i in table_pos]
-            return tuple(call(*tabs, *in_arrays))
-
-        return fn
-
     call = pl.pallas_call(
         body_adapter,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_sc, grid=grid,
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
         out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name=spec.name,            # the kernel's name in HLO and traces
         **kwargs,
     )
+    pos = {t.name: i for i, t in enumerate(spec.inputs)}
+    scalar_pos = [pos[n] for n in scalars]
+    blocked_pos = [pos[t.name] for t in blocked]
 
     def fn(*in_arrays):
-        return tuple(call(*in_arrays))
+        return tuple(call(*(in_arrays[i] for i in scalar_pos),
+                          *(in_arrays[i] for i in blocked_pos)))
 
     return fn
 
 
+def kernel_names(compiled_text: str) -> set:
+    """Names of the compiled Pallas kernels (Mosaic ``tpu_custom_call``s)
+    in a compiled program's HLO text: each is its spec's ``name``."""
+    names = set()
+    for line in compiled_text.splitlines():
+        if "tpu_custom_call" in line:
+            m = re.search(r'op_name="[^"]*?/([A-Za-z0-9_]+)/pallas_call"',
+                          line)
+            if m:
+                names.add(m.group(1))
+    return names
+
+
 def expand(spec: Spec, defines: SimpleNamespace, backend: str, *, interpret: bool = True):
     """Expand one kernel Spec for a backend (the run-time 'macro expansion')."""
-    spec, preps = _lower_halos(spec)
+    spec, preps, views = _lower_halos(spec, align=backend == "pallas")
     if backend in ("jnp", "loops") and math.prod(spec.grid) == 1:
         inner = _expand_single_cell(spec, defines, backend)
     elif backend == "jnp":
@@ -1100,7 +1192,7 @@ def expand(spec: Spec, defines: SimpleNamespace, backend: str, *, interpret: boo
     elif backend == "loops":
         inner = _expand_loops(spec, defines)
     elif backend == "pallas":
-        inner = _expand_pallas(spec, defines, interpret)
+        inner = _expand_pallas(spec, defines, interpret, views)
     else:
         raise ValueError(
             f"unknown backend {backend!r}; expected one of {BACKENDS}")

@@ -278,11 +278,10 @@ class Op:
 
         The collective loop is traced ONCE for all shards (shard_map's SPMD
         contract), so per-shard positions must come from ``lax.axis_index``
-        inside the step hook, never from Python. ``check_rep=False``: the ring
-        writes sharded outputs through explicit collectives the replication
-        checker cannot see."""
+        inside the step hook, never from Python. ``check_vma=False``: the ring
+        writes sharded outputs through explicit collectives the varying-
+        manual-axes checker cannot see."""
         from jax import lax  # deferred: op.py stays import-light
-        from jax.experimental.shard_map import shard_map
 
         sh = self.shard
         if sh is None:
@@ -323,8 +322,9 @@ class Op:
                     x, ax, scatter_dimension=sh.scatter_axis, tiled=True),
                 part)
 
-        fn = shard_map(local, mesh=mesh, in_specs=tuple(sh.in_specs(ax, args)),
-                       out_specs=sh.out_specs(ax), check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=tuple(sh.in_specs(ax, args)),
+                           out_specs=sh.out_specs(ax), check_vma=False)
         return fn(*args)
 
     def __call__(self, *args, **kw):

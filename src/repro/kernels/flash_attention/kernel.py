@@ -13,14 +13,13 @@ jnp/loops/pallas — the bespoke hand-tiled Pallas era is over:
 
 * ``flash_fwd_builder``    forward + lse stats (reduce over kv blocks)
 * ``flash_delta_builder``  fused rowwise ``sum(do * o)`` precompute
-* ``flash_bwd_builder``    ONE fused dq/dk/dv pass: grid (b, h, nq, nk) with
-  BOTH block axes sequential and per-output reduce granularity —
-  ``dq = Tile(reduce=(3,))`` accumulates over k-blocks in scratch while
-  ``dk``/``dv = Tile(reduce=(2,))`` accumulate over q-blocks directly in
-  their (revisited) output blocks. The two hand-tiled backward kernels this
-  replaces had *transposed* reduce orderings; ``Tile(reduce=...)`` expresses
-  both orderings in one grid, recomputing ``p`` once per (qi, ki) tile
-  instead of twice.
+* ``flash_bwd_dkdv_builder`` / ``flash_bwd_dq_builder``  the backward as
+  two passes with transposed grids, each recomputing ``p`` from the lse
+  stats: dk/dv accumulate over the inner q-block sweep (grid
+  (b, h, nk, nq)), dq over the inner k-block sweep (grid (b, h, nq, nk)).
+  Every output block is revisited on CONSECUTIVE grid steps only — the one
+  accumulation pattern a compiled TPU pipeline supports (it writes an
+  output block back when its index changes and never reads it again).
 * ``flash_decode_builder`` single-token decode against a (possibly partially
   filled, possibly ROTATED rolling-window) kv cache; the valid length is a
   dynamic ``kv_len`` input and the slot->absolute-position map a dynamic
@@ -41,7 +40,8 @@ from jax import lax
 
 from repro.core import Scratch, ShardAxis, Spec, Tile, default_device
 
-__all__ = ["flash_fwd_builder", "flash_delta_builder", "flash_bwd_builder",
+__all__ = ["flash_fwd_builder", "flash_delta_builder",
+           "flash_bwd_dkdv_builder", "flash_bwd_dq_builder",
            "flash_decode_builder", "flash_attention_bwd",
            "ring_flash_fwd_builder", "ring_flash_bwd_builder"]
 
@@ -50,7 +50,9 @@ _NEG_INF = float("-inf")
 
 def flash_fwd_builder(D):
     """q: (b, h, sq, d); k: (b, hk, skv, d); v: (b, hk, skv, dv) ->
-    o: (b, h, sq, dv), lse: (b, h, sq) f32 (softmax stats for the backward).
+    o: (b, h, sq, dv), lse: (b, h, sq, 1) f32 (softmax stats for the
+    backward; the trailing singleton keeps the (bq, 1) block legal on the
+    TPU, whose last two block dims must be (8, 128)-aligned or full).
 
     Grid (b, h, nq, nk) with nk the sequential reduce axis; m/l/acc running
     state in scratch, init under ``is_first``, flushed under ``is_last``;
@@ -111,8 +113,7 @@ def flash_fwd_builder(D):
             o_ref[0, 0] = (acc_scr[...] /
                            jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
             # log-sum-exp per query row (softmax stats for the backward kernel)
-            lse_ref[0, 0] = (m_scr[:, 0] +
-                             jnp.log(jnp.where(l[:, 0] == 0.0, 1.0, l[:, 0])))
+            lse_ref[0, 0] = m_scr[:, :1] + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
     return Spec(
         "flash_attention_fwd",
@@ -132,8 +133,8 @@ def flash_fwd_builder(D):
         outputs=[
             Tile("o", (b, h, sq, dv), dtype, block=(1, 1, bq, dv),
                  index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            Tile("lse", (b, h, sq), jnp.float32, block=(1, 1, bq),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi)),
+            Tile("lse", (b, h, sq, 1), jnp.float32, block=(1, 1, bq, 1),
+                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         ],
         body=body)
 
@@ -154,9 +155,10 @@ def _mask_block(q_pos, k_pos, *, causal, window, prefix_len):
 
 
 def _p_block(q, k, lse, mask, sm_scale):
+    """Recomputed softmax block from the saved (bq, 1) row stats."""
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     return jnp.where(mask, p, 0.0)
 
 
@@ -178,7 +180,7 @@ def _run_cond(qi, ki, *, causal, window, prefix_len, block_q, block_kv,
 # ---------------------------------------------------------------------------
 
 def flash_delta_builder(D):
-    """do, o: (b, h, sq, dv) -> delta: (b, h, sq) f32, rowwise sum(do * o).
+    """do, o: (b, h, sq, dv) -> delta: (b, h, sq, 1) f32, rowwise sum(do * o).
 
     The multiply and the row reduction fuse in one grid cell — the (b,h,sq,dv)
     product never materializes."""
@@ -189,7 +191,7 @@ def flash_delta_builder(D):
     def body(ctx, do_ref, o_ref, delta_ref):
         do = do_ref[0, 0].astype(jnp.float32)
         o = o_ref[0, 0].astype(jnp.float32)
-        delta_ref[0, 0] = (do * o).sum(-1)
+        delta_ref[0, 0] = (do * o).sum(-1, keepdims=True)
 
     return Spec(
         "flash_delta",
@@ -201,115 +203,161 @@ def flash_delta_builder(D):
                  index=lambda b_, h_, qi: (b_, h_, qi, 0)),
         ],
         outputs=[
-            Tile("delta", (b, h, sq), jnp.float32, block=(1, 1, bq),
-                 index=lambda b_, h_, qi: (b_, h_, qi)),
+            Tile("delta", (b, h, sq, 1), jnp.float32, block=(1, 1, bq, 1),
+                 index=lambda b_, h_, qi: (b_, h_, qi, 0)),
         ],
         body=body)
 
 
-def flash_bwd_builder(D):
-    """Fused flash backward: q/k/v/do/lse/delta -> dq, dk, dv (per query head).
+def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
+              bq, bkv, q_offset, causal, window, prefix, sm_scale):
+    """One (qi, ki) tile of the backward: (q, k, do, p, ds) in f32."""
+    q = q_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    do = do_ref[0, 0].astype(jnp.float32)
+    q_pos = qi * bq + lax.iota(jnp.int32, bq) + q_offset
+    k_pos = ki * bkv + lax.iota(jnp.int32, bkv)
+    mask = _mask_block(q_pos, k_pos, causal=causal, window=window,
+                       prefix_len=prefix)
+    p = _p_block(q, k, lse_ref[0, 0], mask, sm_scale)           # (bq, bkv)
+    dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0, 0]) * sm_scale                  # (bq, bkv)
+    return q, k, do, p, ds
 
-    Grid (b, h, nq, nk) with BOTH block axes sequential (qi outer, ki inner).
-    ``p`` is recomputed once per (qi, ki) tile from the lse stats and feeds all
-    three cotangents — the per-output reduce granularity does the rest:
 
-      dq  (``reduce=(3,)``)  row state in scratch across the inner ki sweep,
-                             init at ``reduce_first(1)``, flushed at
-                             ``reduce_last(1)`` — nq distinct blocks along the
-                             OUTER sequential axis
-      dk/dv (``reduce=(2,)``) accumulate over the qi sweep directly in their
-                             revisited output blocks (init at
-                             ``reduce_first(0)``) — nk distinct blocks along
-                             the INNER sequential axis
+def _bwd_dims(D):
+    return dict(b=D.b, h=D.h, hk=D.hk, sq=D.sq, skv=D.skv, d=D.d, dv=D.dv,
+                bq=D.block_q, bkv=D.block_kv, g=D.h // D.hk,
+                q_offset=D.skv - D.sq, dtype=jnp.dtype(D.dtype))
 
-    GQA head-group reduction (dk/dv summed over the query-head group) happens
-    on the host in :func:`flash_attention_bwd`."""
-    b, h, hk = D.b, D.h, D.hk
-    sq, skv, d, dv = D.sq, D.skv, D.d, D.dv
-    bq, bkv = D.block_q, D.block_kv
-    causal, window, prefix = D.causal, D.window, D.prefix_len
-    sm_scale = D.sm_scale
-    g = h // hk
-    q_offset = skv - sq
-    nq, nk = sq // bq, skv // bkv
-    dtype = jnp.dtype(D.dtype)
+
+def _bwd_inputs(n, *, qkv_index, row_index):
+    """The six backward inputs; ``qkv_index``/``row_index`` map the grid to
+    (q-block, k-block) ids, so both transposed grids share one declaration."""
+    b, h, hk, sq, skv = n["b"], n["h"], n["hk"], n["sq"], n["skv"]
+    d, dv, bq, bkv, g, dtype = (n["d"], n["dv"], n["bq"], n["bkv"], n["g"],
+                                n["dtype"])
+
+    def qi_of(*ids):
+        return qkv_index(*ids)[0]
+
+    def ki_of(*ids):
+        return qkv_index(*ids)[1]
+
+    return [
+        Tile("q", (b, h, sq, d), dtype, block=(1, 1, bq, d),
+             index=lambda *ids: (ids[0], ids[1], qi_of(*ids), 0)),
+        Tile("k", (b, hk, skv, d), dtype, block=(1, 1, bkv, d),
+             index=lambda *ids: (ids[0], ids[1] // g, ki_of(*ids), 0)),
+        Tile("v", (b, hk, skv, dv), dtype, block=(1, 1, bkv, dv),
+             index=lambda *ids: (ids[0], ids[1] // g, ki_of(*ids), 0)),
+        Tile("do", (b, h, sq, dv), dtype, block=(1, 1, bq, dv),
+             index=lambda *ids: (ids[0], ids[1], qi_of(*ids), 0)),
+        Tile("lse", (b, h, sq, 1), jnp.float32, block=(1, 1, bq, 1),
+             index=row_index),
+        Tile("delta", (b, h, sq, 1), jnp.float32, block=(1, 1, bq, 1),
+             index=row_index),
+    ]
+
+
+def flash_bwd_dkdv_builder(D):
+    """Flash backward, dk/dv pass: grid (b, h, nk, nq), the q-block sweep
+    innermost and sequential. dk/dv (per QUERY head, f32) accumulate in
+    their output blocks across the consecutive q-block visits; the GQA
+    head-group reduction happens on the host in :func:`flash_attention_bwd`."""
+    n = _bwd_dims(D)
+    b, h, skv, d, dv = n["b"], n["h"], n["skv"], n["d"], n["dv"]
+    bq, bkv = n["bq"], n["bkv"]
+    kw = dict(bq=bq, bkv=bkv, q_offset=n["q_offset"], causal=D.causal,
+              window=D.window, prefix=D.prefix_len, sm_scale=D.sm_scale)
 
     def body(ctx, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-             dq_ref, dk_ref, dv_ref):
-        dq_scr, = ctx.scratch
+             dk_ref, dv_ref):
+        ki = ctx.outer_id(2)
         qi = ctx.reduce_id(0)
-        ki = ctx.reduce_id(1)
 
-        @ctx.when(ctx.reduce_first(1))       # ki == 0: a fresh query row
-        def _init_dq():
-            dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
-
-        @ctx.when(ctx.reduce_first(0))       # qi == 0: first visit of dk/dv
-        def _init_dkv():                     # blocks (undefined on real TPU)
+        @ctx.when(ctx.is_first)
+        def _init():
             dk_ref[0, 0] = jnp.zeros((bkv, d), jnp.float32)
             dv_ref[0, 0] = jnp.zeros((bkv, dv), jnp.float32)
 
-        run = _run_cond(qi, ki, causal=causal, window=window,
-                        prefix_len=prefix, block_q=bq, block_kv=bkv,
-                        q_offset=q_offset)
+        run = _run_cond(qi, ki, causal=D.causal, window=D.window,
+                        prefix_len=D.prefix_len, block_q=bq, block_kv=bkv,
+                        q_offset=n["q_offset"])
 
         @ctx.cell_when(run)
         def _step():
-            q = q_ref[0, 0].astype(jnp.float32)
-            k = k_ref[0, 0].astype(jnp.float32)
-            v = v_ref[0, 0].astype(jnp.float32)
-            do = do_ref[0, 0].astype(jnp.float32)
-            lse = lse_ref[0, 0]
-            delta = delta_ref[0, 0]
-            q_pos = qi * bq + lax.iota(jnp.int32, bq) + q_offset
-            k_pos = ki * bkv + lax.iota(jnp.int32, bkv)
-            mask = _mask_block(q_pos, k_pos, causal=causal, window=window,
-                               prefix_len=prefix)
-            p = _p_block(q, k, lse, mask, sm_scale)              # (bq, bkv)
+            q, _, do, p, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                        delta_ref, qi, ki, **kw)
             dv_ref[0, 0] = dv_ref[0, 0] + lax.dot_general(
                 p, do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)              # p^T @ do
-            dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None]) * sm_scale            # (bq, bkv)
             dk_ref[0, 0] = dk_ref[0, 0] + lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)              # ds^T @ q
+
+    return Spec(
+        "flash_attention_bwd_dkdv",
+        grid=(b, h, skv // bkv, n["sq"] // bq),
+        reduce_axes=(3,),
+        inputs=_bwd_inputs(n, qkv_index=lambda b_, h_, ki, qi: (qi, ki),
+                           row_index=lambda b_, h_, ki, qi: (b_, h_, qi, 0)),
+        outputs=[
+            Tile("dk", (b, h, skv, d), jnp.float32, block=(1, 1, bkv, d),
+                 index=lambda b_, h_, ki, qi: (b_, h_, ki, 0)),
+            Tile("dv", (b, h, skv, dv), jnp.float32, block=(1, 1, bkv, dv),
+                 index=lambda b_, h_, ki, qi: (b_, h_, ki, 0)),
+        ],
+        body=body)
+
+
+def flash_bwd_dq_builder(D):
+    """Flash backward, dq pass: grid (b, h, nq, nk), the k-block sweep
+    innermost and sequential; dq accumulates in f32 scratch and is flushed
+    once per query block."""
+    n = _bwd_dims(D)
+    b, h, sq, d = n["b"], n["h"], n["sq"], n["d"]
+    bq, bkv = n["bq"], n["bkv"]
+    kw = dict(bq=bq, bkv=bkv, q_offset=n["q_offset"], causal=D.causal,
+              window=D.window, prefix=D.prefix_len, sm_scale=D.sm_scale)
+
+    def body(ctx, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref):
+        dq_scr, = ctx.scratch
+        qi = ctx.outer_id(2)
+        ki = ctx.reduce_id(0)
+
+        @ctx.when(ctx.is_first)
+        def _init():
+            dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+        run = _run_cond(qi, ki, causal=D.causal, window=D.window,
+                        prefix_len=D.prefix_len, block_q=bq, block_kv=bkv,
+                        q_offset=n["q_offset"])
+
+        @ctx.cell_when(run)
+        def _step():
+            _, k, _, _, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                       delta_ref, qi, ki, **kw)
             dq_scr[...] += lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)              # ds @ k
 
-        @ctx.when(ctx.reduce_last(1))        # ki == nk-1: flush the query row
-        def _flush_dq():
+        @ctx.when(ctx.is_last)
+        def _flush():
             dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
     return Spec(
-        "flash_attention_bwd",
-        grid=(b, h, nq, nk),
-        reduce_axes=(2, 3),
+        "flash_attention_bwd_dq",
+        grid=(b, h, sq // bq, n["skv"] // bkv),
+        reduce_axes=(3,),
         scratch=[Scratch((bq, d), jnp.float32)],
-        inputs=[
-            Tile("q", (b, h, sq, d), dtype, block=(1, 1, bq, d),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            Tile("k", (b, hk, skv, d), dtype, block=(1, 1, bkv, d),
-                 index=lambda b_, h_, qi, ki: (b_, h_ // g, ki, 0)),
-            Tile("v", (b, hk, skv, dv), dtype, block=(1, 1, bkv, dv),
-                 index=lambda b_, h_, qi, ki: (b_, h_ // g, ki, 0)),
-            Tile("do", (b, h, sq, dv), dtype, block=(1, 1, bq, dv),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            Tile("lse", (b, h, sq), jnp.float32, block=(1, 1, bq),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi)),
-            Tile("delta", (b, h, sq), jnp.float32, block=(1, 1, bq),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi)),
-        ],
+        inputs=_bwd_inputs(n, qkv_index=lambda b_, h_, qi, ki: (qi, ki),
+                           row_index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         outputs=[
-            Tile("dq", (b, h, sq, d), dtype, block=(1, 1, bq, d),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0), reduce=(3,)),
-            Tile("dk", (b, h, skv, d), jnp.float32, block=(1, 1, bkv, d),
-                 index=lambda b_, h_, qi, ki: (b_, h_, ki, 0), reduce=(2,)),
-            Tile("dv", (b, h, skv, dv), jnp.float32, block=(1, 1, bkv, dv),
-                 index=lambda b_, h_, qi, ki: (b_, h_, ki, 0), reduce=(2,)),
+            Tile("dq", (b, h, sq, d), n["dtype"], block=(1, 1, bq, d),
+                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         ],
         body=body)
 
@@ -317,8 +365,9 @@ def flash_bwd_builder(D):
 def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=None,
                         sm_scale=None, prefix_len=0, block_q=128,
                         block_kv=128, backend="pallas", interpret=None):
-    """Flash backward host path: delta kernel + fused dq/dk/dv kernel +
-    GQA head-group reduction. Returns (dq, dk, dv)."""
+    """Flash backward host path: delta kernel + the dk/dv and dq kernels +
+    GQA head-group reduction. ``lse`` is (b, h, sq) or (b, h, sq, 1).
+    Returns (dq, dk, dv)."""
     b, h, sq, d = q.shape
     _, hk, skv, _ = k.shape
     dv_dim = v.shape[-1]
@@ -336,12 +385,16 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=None,
         b=b, h=h, sq=sq, dv=dv_dim, block_q=block_q, dtype=dtype))
     delta, = delta_kern.run(do, o.astype(q.dtype))
 
-    bwd_kern = dev.build_kernel(flash_bwd_builder, dict(
+    bwd_defines = dict(
         b=b, h=h, hk=hk, sq=sq, skv=skv, d=d, dv=dv_dim,
         block_q=block_q, block_kv=block_kv, causal=bool(causal),
         window=None if window is None else int(window),
-        prefix_len=int(prefix_len), sm_scale=float(sm_scale), dtype=dtype))
-    dq, dk_h, dv_h = bwd_kern.run(q, k, v, do, lse, delta)
+        prefix_len=int(prefix_len), sm_scale=float(sm_scale), dtype=dtype)
+    lse = lse.reshape(b, h, sq, 1)
+    args = (q, k, v, do, lse, delta)
+    dk_h, dv_h = dev.build_kernel(flash_bwd_dkdv_builder,
+                                  bwd_defines).run(*args)
+    dq, = dev.build_kernel(flash_bwd_dq_builder, bwd_defines).run(*args)
 
     # GQA: reduce dk/dv over the query-head group
     dk = dk_h.reshape(b, hk, g, skv, d).sum(2).astype(k.dtype)
@@ -457,7 +510,7 @@ def flash_decode_builder(D):
 def paged_decode_builder(D):
     """q: (b, h, 1, d) vs a PAGED cache pool k: (P, hk, page, d),
     v: (P, hk, page, dv), block_table: (b, NP) i32, kv_len: (b, 1) i32,
-    pos_pages: (P, page) i32 -> o: (b, h, 1, dv).
+    pos_pages: (P, 1, page) i32 -> o: (b, h, 1, dv).
 
     The continuous-batching decode kernel (vLLM's PagedAttention idiom
     through the unified language): each sequence owns a per-slot list of
@@ -509,7 +562,7 @@ def paged_decode_builder(D):
 
         @ctx.cell_when(run)
         def _step():
-            sp = sp_ref[0]                   # (page,) absolute slot positions
+            sp = sp_ref[0, 0]                # (page,) absolute slot positions
             q = q_ref[0, 0].astype(jnp.float32)          # (1, d)
             k = k_ref[0, 0].astype(jnp.float32)          # (page, d)
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -559,8 +612,9 @@ def paged_decode_builder(D):
                  index=lambda b_, h_, j: (b_, j)),
             Tile("kv_len", (b, 1), jnp.int32, block=(1, 1),
                  index=lambda b_, h_, j: (b_, 0)),
-            Tile("pos_pages", (npages, page), jnp.int32, block=(1, page),
-                 index=lambda b_, h_, j: (0, 0),
+            # (npages, 1, page): a (1, page) block of the full trailing dims
+            Tile("pos_pages", (npages, 1, page), jnp.int32,
+                 block=(1, 1, page), index=lambda b_, h_, j: (0, 0, 0),
                  index_tile=("block_table", 0)),
         ],
         outputs=[
@@ -656,8 +710,7 @@ def ring_flash_fwd_builder(D):
                            jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
             # lse = -inf for fully-masked rows (m stays -inf, l stays 0):
             # exactly the merge identity the host combiner expects
-            lse_ref[0, 0] = (m_scr[:, 0] +
-                             jnp.log(jnp.where(l[:, 0] == 0.0, 1.0, l[:, 0])))
+            lse_ref[0, 0] = m_scr[:, :1] + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
     return Spec(
         "ring_flash_fwd",
@@ -679,8 +732,8 @@ def ring_flash_fwd_builder(D):
         outputs=[
             Tile("o", (b, h, sq, dv), dtype, block=(1, 1, bq, dv),
                  index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            Tile("lse", (b, h, sq), jnp.float32, block=(1, 1, bq),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi)),
+            Tile("lse", (b, h, sq, 1), jnp.float32, block=(1, 1, bq, 1),
+                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         ],
         body=body,
         shard=ShardAxis(mesh_axis=D.mesh_axis, axis=3, extent=D.ring_steps,
@@ -688,12 +741,16 @@ def ring_flash_fwd_builder(D):
 
 
 def ring_flash_bwd_builder(D):
-    """The backward of ONE ring step (see :func:`flash_bwd_builder`).
+    """The backward of ONE ring step: ONE fused dq/dk/dv pass over grid
+    (b, h, nq, nk), both block axes sequential, with the dynamic
+    ``q_start``/``k_start`` offsets, run once per ring step by the host VJP
+    with the step's own lse and an lse-cotangent-adjusted delta
+    (``delta' = delta - g_lse``, since ``ds = p * (dp - delta + g_lse)``
+    when lse is a public output).
 
-    Same fused dq/dk/dv pass with the dynamic ``q_start``/``k_start``
-    offsets, run once per ring step by the host VJP with the step's own lse
-    and an lse-cotangent-adjusted delta (``delta' = delta - g_lse``, since
-    ``ds = p * (dp - delta + g_lse)`` when lse is a public output).
+    dk/dv accumulate over the OUTER q-block axis, so their blocks are
+    revisited non-consecutively: exact on jnp/loops/interpret, refused by
+    the compiled Pallas expansion (see ``lang._expand_pallas``).
 
     The mesh binding mirrors the forward's ring and additionally declares
     ``dk``/``dv`` as shard-resident (grid axis 3 is their SLOT axis — each
@@ -751,14 +808,14 @@ def ring_flash_bwd_builder(D):
             # finite so p is an exact 0, not a masked NaN
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-            p = jnp.exp(jnp.where(mask, s - lse[:, None], 0.0))
+            p = jnp.exp(jnp.where(mask, s - lse, 0.0))
             p = jnp.where(mask, p, 0.0)
             dv_ref[0, 0] = dv_ref[0, 0] + lax.dot_general(
                 p, do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None]) * sm_scale
+            ds = p * (dp - delta) * sm_scale
             dk_ref[0, 0] = dk_ref[0, 0] + lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -784,10 +841,10 @@ def ring_flash_bwd_builder(D):
                  index=lambda b_, h_, qi, ki: (b_, h_ // g, ki, 0)),
             Tile("do", (b, h, sq, dv), dtype, block=(1, 1, bq, dv),
                  index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            Tile("lse", (b, h, sq), jnp.float32, block=(1, 1, bq),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi)),
-            Tile("delta", (b, h, sq), jnp.float32, block=(1, 1, bq),
-                 index=lambda b_, h_, qi, ki: (b_, h_, qi)),
+            Tile("lse", (b, h, sq, 1), jnp.float32, block=(1, 1, bq, 1),
+                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            Tile("delta", (b, h, sq, 1), jnp.float32, block=(1, 1, bq, 1),
+                 index=lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
             Tile("q_start", (1, 1), jnp.int32),
             Tile("k_start", (1, 1), jnp.int32),
         ],

@@ -2,8 +2,8 @@
 
 ``flash_attention`` is one declaration with a fully unified custom VJP: the
 forward runs ``flash_fwd_builder`` on any backend; the backward runs the
-delta-precompute and the ONE fused dq/dk/dv kernel (``flash_bwd_builder``,
-per-output reduce granularity) on the SAME backend, wired through the
+delta-precompute and the dk/dv and dq kernels (``flash_bwd_dkdv_builder``,
+``flash_bwd_dq_builder``) on the SAME backend, wired through the
 front-end's VJP declaration. No O(S^2) residuals are saved — only
 (q, k, v, o, lse); the backward recomputes p blockwise from the lse stats.
 
@@ -76,24 +76,55 @@ def _defines(args, params):
         dtype=jnp.dtype(q.dtype).name)
 
 
+def _pre(args, params):
+    # a causal self-attention longer than a block pads its sequence to a
+    # block multiple (sliced off in _post / _bwd) instead of letting
+    # fit_block degrade to an odd divisor: a TPU block must be a multiple of
+    # (8, 128) or span the whole axis. Exact: the padded keys sit after
+    # every real query, so the causal mask hides them.
+    q, k, v = args
+    s = q.shape[2]
+    if (not params["causal"] or k.shape[2] != s
+            or int(params["prefix_len"]) > s):
+        return args
+    blocks = [b for b in (int(params["block_q"]), int(params["block_kv"]))
+              if b < s]
+    pad = (-s) % math.lcm(*blocks) if blocks else 0
+    if not pad:
+        return args
+    return tuple(jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                 for a in args)
+
+
+def _post(outs, args, params):
+    return outs[0][:, :, :args[0].shape[2]]
+
+
 def _residuals(outs, args, params):
     o, lse = outs
-    q, k, v = args
+    q, k, v = _pre(args, params)
     return q, k, v, o, lse
 
 
 def _bwd(params, res, g):
     q, k, v, o, lse = res
+    s = g.shape[2]
+    pad = o.shape[2] - s
+    if pad:
+        g = jnp.pad(g, ((0, 0), (0, 0), (0, pad), (0, 0)))
     backend = params["backend"]   # already resolved by the VJP front-end
     # re-derive through _defines so fwd and bwd share ONE fitting policy
     # (block sizes, sm_scale default) — the raw requested blocks may not
     # divide the sequence lengths
     D = _defines((q, k, v), params)
-    return flash_attention_bwd(
+    grads = flash_attention_bwd(
         q, k, v, o, g, lse, causal=D["causal"], window=D["window"],
         sm_scale=D["sm_scale"], prefix_len=D["prefix_len"],
         block_q=D["block_q"], block_kv=D["block_kv"], backend=backend,
         interpret=params.get("interpret"))
+    if pad:
+        grads = tuple(d[:, :, :s] for d in grads)
+    return grads
 
 
 def _tune_ref(args, params):
@@ -114,6 +145,8 @@ flash_attention = define_op(
     builder=flash_fwd_builder,
     ref=mha_ref,
     derive_defines=_defines,
+    pre=_pre,
+    post=_post,
     vjp=OpVJP(bwd=_bwd, residuals=_residuals),
     public_outputs=1,                       # lse is residual-only
     defaults=dict(causal=True, window=None, sm_scale=None, prefix_len=0,
@@ -124,19 +157,24 @@ flash_attention = define_op(
     example=_example,
     doc="""Differentiable flash attention. q (B,H,Sq,Dqk), k (B,Hk,Skv,Dqk),
     v (B,Hk,Skv,Dv); supports GQA/MQA, causal, sliding-window and prefix-LM
-    masking. Unified-language forward AND backward (one fused dq/dk/dv
-    kernel) on every backend.""",
+    masking. Unified-language forward AND backward (dk/dv and dq kernels)
+    on every backend; a ragged causal self-attention pads its sequence to
+    the block inside the op.""",
 )
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=None, sm_scale=None,
                         prefix_len=0, block_q=128, block_kv=128,
                         backend="auto", interpret=None):
-    """Forward + lse stats (functional; the op's full kernel output)."""
-    return flash_attention.raw(
+    """Forward + lse stats (b, h, sq) (functional; the op's full kernel
+    output, the kernel's trailing singleton lse axis and any sequence
+    padding dropped)."""
+    o, lse = flash_attention.raw(
         q, k, v, causal=causal, window=window, sm_scale=sm_scale,
         prefix_len=prefix_len, block_q=block_q, block_kv=block_kv,
         backend=backend, interpret=interpret)
+    s = q.shape[2]
+    return o[:, :, :s], lse[:, :, :s, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -275,7 +313,9 @@ def _paged_pre(args, params):
         pos = jnp.full((npages, page), -1, jnp.int32).at[tgt.reshape(-1)].set(
             jnp.broadcast_to(logical, (b, nsp, page)).reshape(-1, page),
             mode="drop")
-    pos = jnp.asarray(pos, jnp.int32).reshape(npages, page)
+    # the kernel's tile is (npages, 1, page): its (1, page) block then spans
+    # the full trailing dims, as the TPU's block-shape rule requires
+    pos = jnp.asarray(pos, jnp.int32).reshape(npages, 1, page)
     return q, k, v, table, kv_len, pos
 
 
@@ -297,7 +337,7 @@ def _paged_defines(args, params):
         raise ValueError(f"flash_decode_paged: v pool shape {v.shape} does "
                          f"not match k pool {k.shape}")
     nsp = table.shape[-1]
-    if tuple(pos.shape) != (npages, page):
+    if tuple(pos.shape) != (npages, 1, page):
         raise ValueError(f"flash_decode_paged: pos_pages shape {pos.shape} "
                          f"does not match the pool ({npages} pages of "
                          f"{page} slots)")

@@ -180,13 +180,13 @@ def _ring_step(frozen, q, k, v, q_start, k_start):
     per shard."""
     o, lse = ring_flash.raw(q, k, v, q_start=q_start, k_start=k_start,
                             **dict(frozen))
-    return o, lse
+    return o, lse[..., 0]
 
 
 def _ring_step_fwd(frozen, q, k, v, q_start, k_start):
     o, lse = ring_flash.raw(q, k, v, q_start=q_start, k_start=k_start,
                             **dict(frozen))
-    return (o, lse), (q, k, v, q_start, k_start, o, lse)
+    return (o, lse[..., 0]), (q, k, v, q_start, k_start, o, lse)
 
 
 def _ring_step_bwd(frozen, res, g):
@@ -206,7 +206,7 @@ def _ring_step_bwd(frozen, res, g):
     # lse is a PUBLIC output of the step (the merge consumes it), so its
     # cotangent lands in the softmax jacobian: ds = p * (dp - delta + g_lse)
     # — the existing fused backward with an adjusted delta
-    delta = delta - g_lse
+    delta = delta - g_lse[..., None]
 
     bwd_kern = dev.build_kernel(ring_flash_bwd_builder, D)
     dq, dk_h, dv_h = bwd_kern.run(q, k, v, do, lse, delta, q_start, k_start)

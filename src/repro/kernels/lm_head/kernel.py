@@ -28,14 +28,12 @@ multi-output-reduce direction ``Tile(reduce=...)`` was built for:
                                 label's logit) ``Tile(reduce=(1, 2))`` ONLY —
                                 the ``(R, V)`` logits never exist.
 
-``lm_head_bwd_builder`` — the CE backward ``softmax(logits) - onehot``
-recomputed blockwise from the saved ``lse`` stats (no logits residual), the
-same transposed-granularity pairing as the fused flash backward: grid
-``(nr, nv)`` with BOTH axes sequential, ``dx = Tile(reduce=(1,))``
-accumulating over vocab blocks in consecutively-revisited output blocks
-while ``dw = Tile(reduce=(0,))`` accumulates over row blocks (write-back/
-refetch revisits — exact on jnp/loops/interpret, flagged for real-TPU
-validation in ROADMAP alongside flash's dk/dv).
+``lm_head_bwd_dx_builder`` / ``lm_head_bwd_dw_builder`` — the CE backward
+``softmax(logits) - onehot`` recomputed blockwise from the saved ``lse``
+stats (no logits residual), as two passes with transposed grids like the
+flash backward: ``dx`` accumulates over the inner vocab sweep (grid
+``(nr, nv)``), ``dw`` over the inner row sweep (grid ``(nv, nr)``), so every
+output block is revisited on consecutive grid steps only.
 
 Vocab padding (``vocab < V``, Megatron-style pad to a sharding multiple) is
 handled INSIDE the kernel: padded columns are excluded from m/l/argmax/gold
@@ -50,7 +48,8 @@ from jax import lax
 
 from repro.core import Scratch, Spec, Tile
 
-__all__ = ["lm_head_builder", "lm_head_bwd_builder"]
+__all__ = ["lm_head_builder", "lm_head_bwd_dx_builder",
+           "lm_head_bwd_dw_builder"]
 
 _NEG_INF = float("-inf")
 _PAD_LOGIT = -1e30
@@ -182,68 +181,98 @@ def lm_head_builder(D):
         body=body)
 
 
-def lm_head_bwd_builder(D):
-    """CE backward: x, w, labels, lse, g -> dx (R, d) f32, dw (d, V) f32.
-
-    ``dlogits = g * (softmax(logits) - onehot(labels))`` recomputed blockwise
-    from the saved ``lse`` (p = exp(s - lse); no logits residual). Grid
-    ``(nr, nv)`` with BOTH axes sequential — the flash-bwd transposed-
-    granularity pairing: ``dx`` accumulates over the inner vocab sweep
-    (consecutive revisits of its output block), ``dw`` over the outer row
-    sweep (write-back/refetch revisits, init under ``reduce_first(0)``).
-    Padded columns produce p == 0 and can never match a valid label, so they
+def _dlogits(x, w, lab, lse, g, vi, *, bv, vocab):
+    """g * (softmax(x @ w) - onehot(labels)) for one (row, vocab) block.
+    Padded columns produce p == 0 and never match a valid label, so they
     contribute nothing — exactly the oracle's gradient through the -1e30
-    mask. The d dimension is unblocked (one (br, d) x tile / (d, bv) w tile
-    per cell), like flash's head_dim."""
-    R, d, V, vocab = D.R, D.d, D.V, D.vocab
+    mask."""
+    s = lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    v_pos = _vocab_positions(vi, bv)                        # (1, bv)
+    valid = v_pos < vocab
+    p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+    hit = (lab == v_pos) & valid                            # (br, bv)
+    return (p - jnp.where(hit, 1.0, 0.0)) * g               # (br, bv)
+
+
+def _bwd_inputs(D, index_rv):
+    """x, w, labels, lse, g — ``index_rv`` maps the grid to (ri, vi)."""
+    R, d, V = D.R, D.d, D.V
     br, bv = D.block_r, D.block_v
     dtype = jnp.dtype(D.dtype)
 
-    def body(ctx, x_ref, w_ref, lab_ref, lse_ref, g_ref, dx_ref, dw_ref):
-        vi = ctx.reduce_id(1)
+    def row(*ids):
+        return (index_rv(*ids)[0], 0)
 
-        @ctx.when(ctx.reduce_first(1))       # vi == 0: fresh row block
-        def _init_dx():
+    return [
+        Tile("x", (R, d), dtype, block=(br, d), index=row),
+        Tile("w", (d, V), dtype, block=(d, bv),
+             index=lambda *ids: (0, index_rv(*ids)[1])),
+        Tile("labels", (R, 1), jnp.int32, block=(br, 1), index=row),
+        Tile("lse", (R, 1), jnp.float32, block=(br, 1), index=row),
+        Tile("g", (R, 1), jnp.float32, block=(br, 1), index=row),
+    ]
+
+
+def lm_head_bwd_dx_builder(D):
+    """CE backward, dx pass: x, w, labels, lse, g -> dx (R, d) f32.
+
+    Grid ``(nr, nv)``, the vocab sweep sequential and innermost: each dx
+    block accumulates across consecutive vocab blocks. The d dimension is
+    unblocked (one (br, d) x tile / (d, bv) w tile per cell), like flash's
+    head_dim."""
+    br, bv, d = D.block_r, D.block_v, D.d
+
+    def body(ctx, x_ref, w_ref, lab_ref, lse_ref, g_ref, dx_ref):
+        vi = ctx.reduce_id(0)
+
+        @ctx.when(ctx.is_first)
+        def _init():
             dx_ref[...] = jnp.zeros((br, d), jnp.float32)
 
-        @ctx.when(ctx.reduce_first(0))       # ri == 0: first visit of this
-        def _init_dw():                      # dw block (undefined on real TPU)
-            dw_ref[...] = jnp.zeros((d, bv), jnp.float32)
-
-        x = x_ref[...].astype(jnp.float32)                  # (br, d)
         w = w_ref[...].astype(jnp.float32)                  # (d, bv)
-        s = lax.dot_general(x, w, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        v_pos = _vocab_positions(vi, bv)                    # (1, bv)
-        valid = v_pos < vocab
-        p = jnp.where(valid, jnp.exp(s - lse_ref[...]), 0.0)
-        hit = (lab_ref[...] == v_pos) & valid               # (br, bv)
-        dl = (p - jnp.where(hit, 1.0, 0.0)) * g_ref[...]    # (br, bv)
+        dl = _dlogits(x_ref[...].astype(jnp.float32), w, lab_ref[...],
+                      lse_ref[...], g_ref[...], vi, bv=bv, vocab=D.vocab)
         dx_ref[...] = dx_ref[...] + lax.dot_general(
             dl, w, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # dl @ w^T
+
+    return Spec(
+        "lm_head_ce_bwd_dx",
+        grid=(D.R // br, D.V // bv),
+        reduce_axes=(1,),
+        inputs=_bwd_inputs(D, lambda ri, vi: (ri, vi)),
+        outputs=[Tile("dx", (D.R, d), jnp.float32, block=(br, d),
+                      index=lambda ri, vi: (ri, 0))],
+        body=body)
+
+
+def lm_head_bwd_dw_builder(D):
+    """CE backward, dw pass: x, w, labels, lse, g -> dw (d, V) f32.
+
+    Grid ``(nv, nr)``, the row sweep sequential and innermost: each dw block
+    accumulates across consecutive row blocks."""
+    br, bv, d = D.block_r, D.block_v, D.d
+
+    def body(ctx, x_ref, w_ref, lab_ref, lse_ref, g_ref, dw_ref):
+        vi = ctx.outer_id(0)
+
+        @ctx.when(ctx.is_first)
+        def _init():
+            dw_ref[...] = jnp.zeros((d, bv), jnp.float32)
+
+        x = x_ref[...].astype(jnp.float32)                  # (br, d)
+        dl = _dlogits(x, w_ref[...].astype(jnp.float32), lab_ref[...],
+                      lse_ref[...], g_ref[...], vi, bv=bv, vocab=D.vocab)
         dw_ref[...] = dw_ref[...] + lax.dot_general(
             x, dl, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # x^T @ dl
 
-    row_tile = dict(block=(br, 1), index=lambda ri, vi: (ri, 0))
     return Spec(
-        "lm_head_ce_bwd",
-        grid=(R // br, V // bv),
-        reduce_axes=(0, 1),
-        inputs=[
-            Tile("x", (R, d), dtype, block=(br, d),
-                 index=lambda ri, vi: (ri, 0)),
-            Tile("w", (d, V), dtype, block=(d, bv),
-                 index=lambda ri, vi: (0, vi)),
-            Tile("labels", (R, 1), jnp.int32, **row_tile),
-            Tile("lse", (R, 1), jnp.float32, **row_tile),
-            Tile("g", (R, 1), jnp.float32, **row_tile),
-        ],
-        outputs=[
-            Tile("dx", (R, d), jnp.float32, block=(br, d),
-                 index=lambda ri, vi: (ri, 0), reduce=(1,)),
-            Tile("dw", (d, V), jnp.float32, block=(d, bv),
-                 index=lambda ri, vi: (0, vi), reduce=(0,)),
-        ],
+        "lm_head_ce_bwd_dw",
+        grid=(D.V // bv, D.R // br),
+        reduce_axes=(1,),
+        inputs=_bwd_inputs(D, lambda vi, ri: (ri, vi)),
+        outputs=[Tile("dw", (d, D.V), jnp.float32, block=(d, bv),
+                      index=lambda vi, ri: (0, vi))],
         body=body)

@@ -21,9 +21,17 @@ def _early(args, params):
 
 
 def _pre(args, params):
+    # rows pad up to a block multiple (sliced off in _post) instead of letting
+    # fit_block degrade to an odd divisor: a TPU row block must be a multiple
+    # of 8 or span every row
     x, w = args
     d = x.shape[-1]
-    return x.reshape(math.prod(x.shape[:-1]), d), w
+    rows = math.prod(x.shape[:-1])
+    pad = (-rows) % min(int(params["block_rows"]), rows)
+    x2 = x.reshape(rows, d)
+    if pad:
+        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
+    return x2, w
 
 
 def _defines(args, params):
@@ -37,7 +45,8 @@ def _defines(args, params):
 
 
 def _post(outs, args, params):
-    return outs[0].reshape(args[0].shape)
+    x = args[0]
+    return outs[0][:math.prod(x.shape[:-1])].reshape(x.shape)
 
 
 def _example(rng):

@@ -30,6 +30,7 @@ import numpy as np
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.models import LM
 from repro.parallel.steps import build_prefill_step, build_serve_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 
 __all__ = ["apply_tuned_winners", "generate", "main"]
@@ -187,6 +188,7 @@ def main(argv=None):
                          "this run performs (default: $REPRO_ANALYZE or error)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.analyze is not None:
         set_analysis_mode(args.analyze)
     cfg = get_config(args.arch)

@@ -26,6 +26,7 @@ from repro.models import LM
 from repro.optim import AdamW, WarmupCosine
 from repro.parallel.steps import build_train_step
 from repro.runtime import ChaosError, FailureInjector, StepWatchdog
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 
 __all__ = ["TrainLoop", "apply_tuned_winners", "main", "validate_host_batch"]
@@ -202,6 +203,7 @@ def main(argv=None):
                          "this run performs (default: $REPRO_ANALYZE or error)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.analyze is not None:
         set_analysis_mode(args.analyze)
     cfg = get_config(args.arch)

@@ -146,21 +146,19 @@ def _winner_overflows(op, args, params, winner) -> bool:
     """True when a persisted winner's static VMEM footprint exceeds the
     current ``$REPRO_VMEM_BUDGET`` at these probe shapes — a stale entry
     tuned under other constraints must not be adopted (the very first build
-    would raise VMEM_OVERFLOW). Best-effort: unmodelable winners adopt."""
+    would raise VMEM_OVERFLOW). A winner that cannot even build at these
+    shapes is a corrupt cache entry: the error propagates."""
     from types import SimpleNamespace
 
     from repro.core.analyze import vmem_budget, vmem_footprint
 
-    try:
-        # sweep keys may be op params (flash_decode's block_kv) or bare
-        # defines (matmul's bm/bn/bk): route each winner key accordingly
-        pwin = {k: v for k, v in winner.items() if k in op.defaults}
-        _, _, params = op._resolve(dict(params, **pwin))
-        _, defines, _ = op._prepare(tuple(args), params)
-        spec = op.builder(SimpleNamespace(**dict(defines, **winner)))
-        return vmem_footprint(spec)[0] > vmem_budget()
-    except Exception:
-        return False
+    # sweep keys may be op params (flash_decode's block_kv) or bare
+    # defines (matmul's bm/bn/bk): route each winner key accordingly
+    pwin = {k: v for k, v in winner.items() if k in op.defaults}
+    _, _, params = op._resolve(dict(params, **pwin))
+    _, defines, _ = op._prepare(tuple(args), params)
+    spec = op.builder(SimpleNamespace(**dict(defines, **winner)))
+    return vmem_footprint(spec)[0] > vmem_budget()
 
 
 def adopt(cfg, shapes: dict, *, kind: str) -> dict:
@@ -203,10 +201,7 @@ def adopt_winners(probes: dict) -> dict:
         op = registered_ops().get(name)
         if op is None:
             continue
-        try:
-            winner = op.cached_winner(args, **params)
-        except Exception:
-            continue  # probe shape invalid for this arch: no winner to adopt
+        winner = op.cached_winner(args, **params)
         if winner and _winner_overflows(op, args, params, winner):
             continue
         if winner:

@@ -1,9 +1,10 @@
 """Attention layers: GQA (covers MHA/MQA/SWA/prefix-LM) and MLA (deepseek).
 
 Each variant provides init / forward (train+prefill) / cache init / decode.
-The perf-critical realization is selected at run time via
-``use_kernel_backend``: "pallas" -> repro.kernels flash kernels, "jnp" ->
-oracle paths (mha_ref for short, mha_chunked for long sequences). Decode
+The perf-critical realization follows ``kernel_backend()`` (the platform:
+kernels on a TPU, references elsewhere; ``use_kernel_backend`` pins one):
+"pallas" -> repro.kernels flash kernels, "jnp" -> oracle paths (mha_ref for
+short, mha_chunked for long sequences). Decode
 under "pallas" runs the registered ``flash_decode`` op against the
 preallocated cache for EVERY layout — dynamic ``kv_len`` masks the unfilled
 tail, and rolling-window caches pass their rotated-slot position map as the
@@ -20,7 +21,8 @@ from repro.kernels.flash_attention import (decode_attention, decode_ref,
                                            mha_ref, paged_decode_attention,
                                            paged_decode_ref,
                                            ring_flash_attention)
-from repro.parallel.context import current_rules, shard_activation
+from repro.parallel.context import (current_rules, shard_activation,
+                                    shard_kernel)
 from repro.parallel.rules import ring_axis_for
 
 from .common import dense_init, kernel_backend, rmsnorm
@@ -34,6 +36,10 @@ __all__ = [
 ]
 
 _CHUNKED_THRESHOLD = 8192  # jnp path switches to q-block-chunked beyond this
+# kernel calls on a mesh: batch over the batch axes, heads over the model
+# axis (see ``shard_kernel``)
+_BHSD = ("batch", "model", None, None)
+_HEADS = (None, "model", None, None)
 
 
 def _ring_target(seq_len):
@@ -48,6 +54,14 @@ def _ring_target(seq_len):
     if ax is None:
         return None, None
     return rules.mesh, ax
+
+
+def _causal_flash(q, k, v, **kw):
+    """Causal flash attention, per shard on a mesh (``shard_kernel``); the
+    op pads a ragged sequence to its block."""
+    return shard_kernel(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, **kw),
+        (q, k, v), (_BHSD,) * 3, _BHSD)
 
 
 # ===========================================================================
@@ -97,8 +111,7 @@ def gqa_forward(params, x, cfg, *, positions=None, prefix_len=0,
             prefix_len=prefix_len,
             backend="auto" if kernel_backend() == "pallas" else "jnp")
     elif kernel_backend() == "pallas":
-        o = flash_attention(q, k, v, causal=True, window=cfg.window,
-                            prefix_len=prefix_len)
+        o = _causal_flash(q, k, v, window=cfg.window, prefix_len=prefix_len)
     elif s > _CHUNKED_THRESHOLD:
         o = mha_chunked(q, k, v, causal=True, window=cfg.window,
                         prefix_len=prefix_len)
@@ -184,11 +197,13 @@ def gqa_decode(params, x, cache, cfg):
         # as a traced kv_len. Rolling-window caches store ROTATED slots
         # (slot = pos % W); their data-dependent mask rides in as the
         # slot_pos input tile — the grouped-einsum fallback is gone.
-        o = decode_attention(
-            q, cache["k"], cache["v"], kv_len=kv_len,
-            window=cfg.window if cfg.window else None,
-            slot_pos=cache["slot_pos"] if cfg.window else None,
-            sm_scale=hd ** -0.5)
+        slot_pos = cache["slot_pos"] if cfg.window else None
+        o = shard_kernel(
+            lambda q, k, v, n, sp: decode_attention(
+                q, k, v, kv_len=n, window=cfg.window if cfg.window else None,
+                slot_pos=sp, sm_scale=hd ** -0.5),
+            (q, cache["k"], cache["v"], kv_len, slot_pos),
+            (_BHSD, _BHSD, _BHSD, (), (None,)), _BHSD)
     else:
         # the slot_pos-aware oracle covers BOTH layouts with one grouped
         # masked einsum (no kv replication in HBM; the cache is consumed in
@@ -238,10 +253,16 @@ def gqa_paged_decode(params, x, cache, cfg, *, table, lens, pos_pages,
     vp = vp.at[page_ids, :, offs].set(v1[:, :, 0].astype(vp.dtype))
     kv_len = lens + 1
     if kernel_backend() == "pallas":
-        o = paged_decode_attention(q, kp, vp, block_table=table,
-                                   kv_len=kv_len, pos_pages=pos_pages,
-                                   window=cfg.window if cfg.window else None,
-                                   sm_scale=hd ** -0.5)
+        # the pools have no batch dim: every shard holds all pages of its
+        # kv heads, and the control state replicates
+        o = shard_kernel(
+            lambda q, kp, vp, t, n, pp: paged_decode_attention(
+                q, kp, vp, block_table=t, kv_len=n, pos_pages=pp,
+                window=cfg.window if cfg.window else None,
+                sm_scale=hd ** -0.5),
+            (q, kp, vp, table, kv_len, pos_pages),
+            (_HEADS, _HEADS, _HEADS, (None, None), (None,), (None, None)),
+            _HEADS)
     else:
         o = paged_decode_ref(q, kp, vp, block_table=table, kv_len=kv_len,
                              pos_pages=pos_pages,
@@ -299,7 +320,7 @@ def mla_forward(params, x, cfg, *, positions=None, return_latent=False):
     q = shard_activation(q, "act_bhsd")
     k = shard_activation(k, "act_bhsd")
     if kernel_backend() == "pallas":
-        o = flash_attention(q, k, v, causal=True)
+        o = _causal_flash(q, k, v)
     elif s > _CHUNKED_THRESHOLD:
         o = mha_chunked(q, k, v, causal=True)
     else:

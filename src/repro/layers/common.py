@@ -10,25 +10,38 @@ import jax.numpy as jnp
 
 from repro.kernels.rmsnorm import rmsnorm as rmsnorm_pallas_op
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
+from repro.parallel.context import shard_kernel
 
 __all__ = [
     "dense_init", "rms_init", "rmsnorm", "kernel_backend", "use_kernel_backend",
     "silu", "softplus",
 ]
 
-# Which realization the perf-critical ops use: "jnp" (XLA-fused reference,
-# used for the multi-pod dry-run) or "pallas" (TPU kernels; interpret on CPU).
-_kernel_backend: contextvars.ContextVar[str] = contextvars.ContextVar(
-    "repro_kernel_backend", default="jnp")
+# Which realization the perf-critical ops use: "pallas" (the kernels) on a
+# TPU, "jnp" (the XLA references) elsewhere. ``use_kernel_backend`` pins
+# one for a scope (tests compare the two; pallas runs interpreted off-TPU).
+_kernel_backend: contextvars.ContextVar[str | None] = contextvars.ContextVar(
+    "repro_kernel_backend", default=None)
+
+# ops the TPU compiler refuses: on a TPU their layers keep the XLA reference
+# unless a scope pins "pallas" (ssm_scan: no dynamic_slice lowering)
+TPU_REFUSED = frozenset({"ssm_scan"})
 
 
-def kernel_backend() -> str:
-    return _kernel_backend.get()
+def kernel_backend(op: str | None = None) -> str:
+    """The realization for ``op`` (None: any op not in ``TPU_REFUSED``)."""
+    pinned = _kernel_backend.get()
+    if pinned is not None:
+        return pinned
+    if jax.default_backend() == "tpu" and op not in TPU_REFUSED:
+        return "pallas"
+    return "jnp"
 
 
 @contextlib.contextmanager
 def use_kernel_backend(name: str):
-    assert name in ("jnp", "pallas"), name
+    if name not in ("jnp", "pallas"):
+        raise ValueError(f"kernel backend must be jnp|pallas, got {name!r}")
     tok = _kernel_backend.set(name)
     try:
         yield
@@ -50,7 +63,9 @@ def rms_init(shape):
 
 def rmsnorm(x, w, *, eps=1e-6):
     if kernel_backend() == "pallas":
-        return rmsnorm_pallas_op(x, w, eps=eps)
+        rows = ("batch",) + (None,) * (x.ndim - 1)
+        return shard_kernel(lambda x, w: rmsnorm_pallas_op(x, w, eps=eps),
+                            (x, w), (rows, (None,)), rows)
     return rmsnorm_ref(x, w, eps=eps)
 
 

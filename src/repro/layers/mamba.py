@@ -1,8 +1,10 @@
 """Mamba layers: mamba1 (falcon-mamba) and mamba2/SSD (zamba2).
 
 Training uses memory-sane chunked scans (lax.scan over time chunks — nothing
-(B, L, D, N)-shaped is ever materialized); mamba1 can route through the
-fused Pallas ssm_scan kernel. Decode is a single-step state update.
+(B, L, D, N)-shaped is ever materialized); both can route through the
+fused Pallas ssm_scan kernel when ``kernel_backend("ssm_scan")`` says so
+(only when pinned: the TPU compiler refuses it). Decode is a single-step
+state update.
 """
 
 from __future__ import annotations
@@ -113,7 +115,7 @@ def mamba1_forward(params, x, cfg):
     xi = silu(_causal_conv(xi, params["conv_w"], params["conv_b"]).astype(xi.dtype))
     dt, Bm, Cm = _mamba1_dtbc(params, xi, cfg)
     A = -jnp.exp(params["A_log"])
-    if kernel_backend() == "pallas":
+    if kernel_backend("ssm_scan") == "pallas":
         y = ssm_scan(xi, dt, A, Bm, Cm, params["D"])
     else:
         y, _ = _chunked_scan_jnp(xi, dt, A, Bm, Cm, params["D"])
@@ -256,7 +258,7 @@ def mamba2_forward(params, x, cfg, *, return_state=False, h0=None):
     Cm = xBC[..., di + n:]
     dt = softplus(dt + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
-    if kernel_backend() == "pallas" and not return_state:
+    if kernel_backend("ssm_scan") == "pallas" and not return_state:
         # mamba2 maps EXACTLY onto the fused per-channel kernel (ngroups=1):
         # per-head dt/A/D broadcast to their channels, B/C stay shared —
         # the same recurrence the SSD form factorizes per head.

@@ -42,12 +42,15 @@ def _aux_builders(op_name: str) -> list:
     own) — linted with the op's defines, which are a superset of theirs."""
     if op_name == "flash_attention":
         from repro.kernels.flash_attention.kernel import (
-            flash_bwd_builder, flash_delta_builder)
+            flash_bwd_dkdv_builder, flash_bwd_dq_builder, flash_delta_builder)
         return [("flash_attention/delta", flash_delta_builder),
-                ("flash_attention/bwd", flash_bwd_builder)]
+                ("flash_attention/bwd_dkdv", flash_bwd_dkdv_builder),
+                ("flash_attention/bwd_dq", flash_bwd_dq_builder)]
     if op_name == "lm_head_ce":
-        from repro.kernels.lm_head.kernel import lm_head_bwd_builder
-        return [("lm_head_ce/bwd", lm_head_bwd_builder)]
+        from repro.kernels.lm_head.kernel import (lm_head_bwd_dw_builder,
+                                                  lm_head_bwd_dx_builder)
+        return [("lm_head_ce/bwd_dx", lm_head_bwd_dx_builder),
+                ("lm_head_ce/bwd_dw", lm_head_bwd_dw_builder)]
     if op_name == "ring_flash":
         from repro.kernels.flash_attention.kernel import (
             flash_delta_builder, ring_flash_bwd_builder)

@@ -21,7 +21,12 @@ from repro.kernels.lm_head import lm_head_ce, lm_head_logits
 from repro.layers import blocks
 from repro.layers.common import dense_init, rmsnorm
 from repro.layers.rope import sinusoidal_embedding
-from repro.parallel.context import shard_activation
+from repro.parallel.context import shard_activation, shard_kernel
+
+# the fused head's kernel calls on a mesh: rows over the batch axes, the
+# head matrix whole on every shard (see ``shard_kernel``)
+_ROWS = ("batch", None)
+_WHOLE = (None, None)
 
 __all__ = ["LM", "StackSpec", "build_program", "pad_vocab"]
 
@@ -178,10 +183,11 @@ class LM:
         head = self._head(params)
         if self.fused_head:
             b, s, d = x.shape
-            logits = lm_head_logits(x.reshape(b * s, d),
-                                    head.astype(x.dtype),
-                                    vocab=cfg.vocab_size,
-                                    backend=self.head_backend)
+            logits = shard_kernel(
+                lambda x, w: lm_head_logits(x, w, vocab=cfg.vocab_size,
+                                            backend=self.head_backend),
+                (x.reshape(b * s, d), head.astype(x.dtype)),
+                (_ROWS, _WHOLE), _ROWS)
             return shard_activation(logits.reshape(b, s, self.vpad),
                                     "act_btv")
         logits = jnp.einsum("...d,dv->...v", x, head,
@@ -275,10 +281,12 @@ class LM:
         from the saved row stats)."""
         b, s, d = x.shape
         head = self._head(params).astype(x.dtype)
-        nll = lm_head_ce(x.reshape(b * s, d), head,
-                         labels.reshape(b * s, 1).astype(jnp.int32),
-                         vocab=self.cfg.vocab_size,
-                         backend=self.head_backend)
+        nll = shard_kernel(
+            lambda x, w, lab: lm_head_ce(x, w, lab, vocab=self.cfg.vocab_size,
+                                         backend=self.head_backend),
+            (x.reshape(b * s, d), head,
+             labels.reshape(b * s, 1).astype(jnp.int32)),
+            (_ROWS, _WHOLE, _ROWS), ("batch",))
         return nll.mean()
 
     def _check_labels(self, labels):
@@ -497,15 +505,27 @@ class LM:
         if not self.fused_head:
             logits = self._logits(params, x)[:, 0]
             return self.greedy_token(logits), logits, new_cache
+        logits, arg = self._greedy_head(params, x)
+        return arg, logits, new_cache
+
+    def _greedy_head(self, params, x):
+        """(logits (B, Vpad), argmax (B,)) of decode hidden states
+        x (B, 1, d) from one fused LM-head kernel pass."""
         b, s, d = x.shape                    # s == 1
-        # .raw returns the kernel outputs unsliced — drop any pre-hook row
-        # padding (none at decode batch sizes, but keep the contract local)
-        logits, _m, arg = lm_head_logits.raw(
-            x.reshape(b, d), self._head(params).astype(x.dtype),
-            vocab=self.cfg.vocab_size, backend=self.head_backend)
-        logits = shard_activation(logits[:b].reshape(b, 1, self.vpad),
+
+        def head(x, w):
+            # .raw returns the kernel outputs unsliced: drop the pre-hook's
+            # row padding per shard, before the shards concatenate
+            logits, _m, arg = lm_head_logits.raw(
+                x, w, vocab=self.cfg.vocab_size, backend=self.head_backend)
+            return logits[:x.shape[0]], arg[:x.shape[0]]
+
+        logits, arg = shard_kernel(
+            head, (x.reshape(b, d), self._head(params).astype(x.dtype)),
+            (_ROWS, _WHOLE), (_ROWS, _ROWS))
+        logits = shard_activation(logits.reshape(b, 1, self.vpad),
                                   "act_btv")[:, 0]
-        return arg[:b, 0], logits, new_cache
+        return logits, arg[:, 0]
 
     def greedy_token(self, logits):
         return jnp.argmax(logits[..., :self.cfg.vocab_size], axis=-1)
@@ -598,10 +618,5 @@ class LM:
         if not self.fused_head:
             logits = self._logits(params, x)[:, 0]
             return self.greedy_token(logits), logits, new_cache
-        b, s, d = x.shape
-        logits, _m, arg = lm_head_logits.raw(
-            x.reshape(b, d), self._head(params).astype(x.dtype),
-            vocab=self.cfg.vocab_size, backend=self.head_backend)
-        logits = shard_activation(logits[:b].reshape(b, 1, self.vpad),
-                                  "act_btv")[:, 0]
-        return arg[:b, 0], logits, new_cache
+        logits, arg = self._greedy_head(params, x)
+        return arg, logits, new_cache

@@ -20,7 +20,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -77,7 +76,7 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, *, mesh: Mesh,
         return jax.lax.psum(outs, axis)
 
     pipe_spec = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(pipe_spec, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(pipe_spec, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_microbatches)

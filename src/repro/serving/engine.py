@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.device import fit_block
+from repro.parallel.context import use_rules
 
 from .scheduler import Scheduler
 
@@ -56,13 +57,10 @@ class Engine:
             # a pre-tuned fleet serves at its tuned block.
             from repro.kernels.flash_attention import flash_decode
             from repro.launch import tuning
-            try:
-                tuning.adopt(model.cfg, dict(batch=batch, prompt_len=max_len,
-                                             max_len=max_len), kind="serve")
-            except Exception:
-                pass
-            page_size = fit_block(
-                int(flash_decode.defaults.get("block_kv") or 512), max_len)
+            tuning.adopt(model.cfg, dict(batch=batch, prompt_len=max_len,
+                                         max_len=max_len), kind="serve")
+            page_size = fit_block(int(flash_decode.defaults["block_kv"]),
+                                  max_len)
         self.page_size = int(page_size)
         nsp = -(-max_len // self.page_size)
         if num_pages is None:
@@ -78,20 +76,30 @@ class Engine:
         self.cache = model.init_paged_cache(batch, num_pages, self.page_size,
                                             nsp, dtype=cache_dtype)
         self._requests = {}
+        self.last_decode = None
         self._pending = np.zeros((batch,), np.int32)
         self._slot_pages = [[] for _ in range(batch)]
+        rules = None
         if mesh is not None:
             from repro.parallel.steps import build_paged_serve_step
             self._step_fn, specs = build_paged_serve_step(
                 model, mesh, batch=batch, greedy=greedy)
             self.params = jax.device_put(params, specs["params"])
             self.cache = jax.device_put(self.cache, specs["cache"])
+            rules = specs["rules"]
         else:
             self.params = params
             fn = model.paged_greedy_step if greedy else model.paged_decode_step
             self._step_fn = jax.jit(lambda p, c, t: fn(p, t, c),
                                     donate_argnums=(1,))
-        self._prefill_fn = jax.jit(lambda p, t: model.prefill(p, t))
+
+        def _prefill(p, t):
+            # on a mesh, under the step's rules: the kernels then run per
+            # shard (``shard_kernel``) like the decode step's
+            with use_rules(rules):
+                return model.prefill(p, t)
+
+        self._prefill_fn = jax.jit(_prefill)
 
         # admission scatter, fused: ALL stacks' pages + pos rows land in one
         # jitted call (the eager .at[].set chain was ~10 dispatches per
@@ -161,6 +169,13 @@ class Engine:
     @property
     def idle(self) -> bool:
         return self.sched.idle
+
+    @property
+    def decode_step(self):
+        """The jitted one-token decode step every engine step runs:
+        ``(params, cache, tokens (batch, 1)) -> (next, logits, cache)``
+        (greedy) or ``(logits, cache)``."""
+        return self._step_fn
 
     # ------------------------------------------------------- device mirrors
     def _table_row(self, pages: list[int]) -> np.ndarray:
@@ -256,12 +271,16 @@ class Engine:
             return emitted
         toks = jnp.asarray(self._pending.reshape(-1, 1))
         if self.greedy:
-            nxt, _logits, self.cache = self._step_fn(self.params, self.cache,
-                                                     toks)
+            nxt, logits, self.cache = self._step_fn(self.params, self.cache,
+                                                    toks)
             nxt = np.asarray(nxt)
         else:
             logits, self.cache = self._step_fn(self.params, self.cache, toks)
             nxt = self._sample(np.asarray(logits))
+        # this step's (batch, Vpad) logits, on device, and which request
+        # each running slot held — for callers that inspect the decode
+        self.last_decode = (
+            logits, {self.sched.slots[s].rid: s for s in running})
         for slot in running:
             tok = int(nxt[slot])
             self._pending[slot] = tok

@@ -25,8 +25,10 @@ def mesh8():
     subprocess-based parity tests cover the shard_map path instead."""
     import jax
 
+    from repro.launch.mesh import make_mesh
+
     if jax.device_count() < 8:
         pytest.skip("needs 8 devices (set XLA_FLAGS="
                     "--xla_force_host_platform_device_count=8 before jax "
                     "imports — the scripts/ci.sh mesh leg does)")
-    return jax.make_mesh((8,), ("model",))
+    return make_mesh((8,), ("model",))

@@ -356,12 +356,14 @@ def test_lowered_text_uses_prejitted_kernel():
 def test_flash_bwd_uses_fitted_blocks():
     """Forward fits block sizes to the sequence; the backward must reuse the
     fitted sizes (regression: grad crashed on non-dividing shapes)."""
-    b, h, s, d = 1, 2, 80, 32  # 80 % 64 != 0 -> fit_block degrades to 40
+    # 80 % 64 != 0 -> fit_block degrades to 40 (non-causal: a causal
+    # sequence would pad to the block instead)
+    b, h, s, d = 1, 2, 80, 32
     rng = np.random.RandomState(11)
     q, k, v = (jnp.asarray(rng.randn(b, h, s, d), jnp.float32) for _ in range(3))
     gk = jax.grad(lambda q_: (flash_attention(
-        q_, k, v, block_q=64, block_kv=64) ** 2).sum())(q)
-    gr = jax.grad(lambda q_: (mha_ref(q_, k, v) ** 2).sum())(q)
+        q_, k, v, causal=False, block_q=64, block_kv=64) ** 2).sum())(q)
+    gr = jax.grad(lambda q_: (mha_ref(q_, k, v, causal=False) ** 2).sum())(q)
     np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
                                rtol=2e-3, atol=2e-3)
 

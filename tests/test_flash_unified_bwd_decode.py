@@ -128,6 +128,24 @@ def test_partial_reduce_blocks_must_cover_output():
                            index=lambda o, a, b: (o, a), reduce=(2,)))
 
 
+def test_compiled_pallas_refuses_non_consecutive_accumulation():
+    """Accumulating over an axis OUTER to the slot axis revisits a block on
+    non-consecutive grid steps: exact on jnp/loops/interpret, but a compiled
+    TPU pipeline never re-reads a written-back output block — the compiled
+    expansion refuses it at build time (the old fused flash dk/dv pattern)."""
+    from repro.core.lang import defines_namespace, expand
+
+    bad = _one_out_spec(Tile("y", (2, 2), jnp.float32, block=(1, 1),
+                             index=lambda o, a, b: (o, b), reduce=(1,)))
+    D = defines_namespace({})
+    expand(bad, D, "pallas", interpret=True)         # interpreter: fine
+    with pytest.raises(ValueError, match="non-consecutively"):
+        expand(bad, D, "pallas", interpret=False)
+    good = granularity_builder(defines_namespace(dict(no=2, n0=3, n1=4,
+                                                      bn=5)))
+    expand(good, D, "pallas", interpret=False)       # inner accumulation ok
+
+
 # ---------------------------------------------------------------------------
 # flash backward: ONE fused dq/dk/dv kernel vs the oracle, every backend
 # ---------------------------------------------------------------------------
@@ -221,6 +239,38 @@ def test_flash_decode_window_smaller_than_kv_block(backend):
     ref = decode_ref(q, k, v, window=7)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_flash_pads_ragged_causal_sequence(backend):
+    """A causal self-attention longer than a block pads to the block inside
+    the op (a TPU block must be (8, 128)-aligned or span the axis): the
+    kernel sees 256 rows for 200, and output, lse and grads are exact."""
+    from repro.kernels.flash_attention import flash_attention_fwd
+
+    b, h, hk, s, d = 1, 4, 2, 200, 32
+    rng = np.random.RandomState(12)
+    q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(b, hk, s, d), jnp.float32)
+            for _ in range(2))
+    kw = dict(block_q=64, block_kv=128, backend=backend)
+    _, _, params = flash_attention._resolve(kw)
+    (qp, kp, vp), defines, _ = flash_attention._prepare((q, k, v), params)
+    assert qp.shape[2] == kp.shape[2] == 256
+    assert (defines["block_q"], defines["block_kv"]) == (64, 128)
+    o = flash_attention(q, k, v, **kw)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(mha_ref(q, k, v)),
+                               rtol=2e-4, atol=2e-4)
+    o2, lse = flash_attention_fwd(q, k, v, **kw)
+    assert o2.shape == o.shape and lse.shape == (b, h, s)
+    got = jax.grad(lambda *a: (flash_attention(*a, **kw) ** 2).sum(),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (mha_ref(*a) ** 2).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-3, atol=2e-3)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

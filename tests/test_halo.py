@@ -217,3 +217,26 @@ def test_fallback_cost_still_amplifies_halo_blocks():
                  dict(h=8, w=8, bh=4, bw=4, r0=2, r1=2, wrap=True))
     fallback = estimate_cost(spec, walk=False)
     assert fallback.bytes_in == 4 * 8 * 8 * 4  # 4 cells x full window
+
+
+def test_pallas_halo_windows_are_tpu_aligned():
+    """The compiled TPU expansion needs the trailing two block dims to be
+    multiples of (sublanes, 128) or full: the pallas lowering widens fd2d's
+    (bh + 2r, bw + 2r) = (34, 258) window to (40, 384), continuing the wrap,
+    while jnp/loops keep the exact window."""
+    from repro.core.lang import _lower_halo_tile
+
+    t = Tile("u", (4096, 4096), jnp.float32, block=(32, 256), halo=(1, 1))
+    exact, _ = _lower_halo_tile(t)
+    assert exact.block == (34, 258)
+    aligned, windowize = _lower_halo_tile(t, align=True)
+    assert aligned.block == (40, 384)
+    assert aligned.shape == (128 * 40, 16 * 384)
+    u = np.arange(64 * 256, dtype=np.float32).reshape(64, 256)
+    small = Tile("u", (64, 256), jnp.float32, block=(32, 128), halo=(1, 1))
+    lowered, windowize = _lower_halo_tile(small, align=True)
+    win = np.asarray(windowize(jnp.asarray(u)))[:lowered.block[0],
+                                                 :lowered.block[1]]
+    # block (0, 0): rows/cols -1 .. wrap around, then continue in order
+    assert win[0, 0] == u[-1, -1] and win[1, 1] == u[0, 0]
+    np.testing.assert_array_equal(win[1:34, 1:130], u[0:33, 0:129])

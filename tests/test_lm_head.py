@@ -485,3 +485,32 @@ def test_tune_cli_list_and_arch_mode(tmp_path, monkeypatch, capsys):
     out = capsys.readouterr().out
     assert "lm_head_ce: winner" in out
     assert list((tmp_path / "autotune").glob("*.json"))
+
+
+def test_fit_block_prefers_lane_multiples():
+    from repro.core import fit_block
+
+    assert fit_block(512, 128256) == 501              # plain largest divisor
+    assert fit_block(512, 128256, 128) == 384         # lane-aligned divisor
+    assert fit_block(512, 32000, 128) == 256
+    assert fit_block(200, 300, 128) == 150            # none aligned: plain
+    assert fit_block(512, 384, 128) == 384            # whole axis
+
+
+@pytest.mark.parametrize("d,vpad", [(4096, 49408), (3584, 32000),
+                                    (2048, 128256)])
+def test_bwd_blocks_stay_tpu_aligned(d, vpad):
+    """At wide d the backward's VMEM fit shrinks vocab blocks only while
+    they stay 128-aligned, then row blocks (8-aligned): a TPU block's last
+    two dims must be (8, 128) multiples or span the axis."""
+    from repro.kernels.lm_head.ops import _base_defines, _fit_bwd_vmem
+
+    rows = 4 * 2048
+    x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((d, vpad), jnp.bfloat16)
+    D = _base_defines(x, w, dict(lm_head_ce.defaults), op_name="lm_head_ce")
+    assert D["block_v"] % 128 == 0 and D["block_k"] % 128 == 0
+    b = _fit_bwd_vmem({k: D[k] for k in ("R", "d", "V", "vocab", "block_r",
+                                         "block_v", "dtype")})
+    assert b["block_v"] % 128 == 0 and b["block_r"] % 8 == 0
+    assert vpad % b["block_v"] == 0 and rows % b["block_r"] == 0

@@ -20,6 +20,7 @@ from repro.core import AnalysisError, ShardAxis, Spec, Tile, estimate_cost
 from repro.core.lang import defines_namespace
 from repro.kernels.flash_attention import flash_attention, ring_flash, \
     ring_flash_attention
+from repro.launch.mesh import make_local_mesh
 
 
 def _ring_specs(which="fwd", **over):
@@ -193,9 +194,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.flash_attention import flash_attention, ring_flash_attention
 import repro.layers.attention as attn
+from repro.launch.mesh import make_mesh
 from repro.parallel.context import Rules, use_rules
 
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 rng = np.random.RandomState(0)
 q = rng.randn(1, 4, 256, 32).astype("float32")
 k = rng.randn(1, 2, 256, 32).astype("float32")
@@ -246,6 +248,66 @@ def test_ring_shard_map_matches_single_device_subprocess():
     assert rec["layer"] < 1e-4, rec
 
 
+_ENGINE_SUB = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import numpy as np, jax
+
+from repro.configs import get_config, reduced
+from repro.launch.mesh import make_mesh
+from repro.layers.common import use_kernel_backend
+from repro.models import LM
+from repro.serving import Engine
+
+cfg = reduced(get_config("internlm2_1_8b"))       # 4 heads, 2 kv heads
+mesh = make_mesh((2, 2), ("data", "model"))
+rng = np.random.RandomState(0)
+prompts = [rng.randint(0, cfg.vocab_size, n).tolist() for n in (5, 9, 3, 7)]
+out, logits, jaxprs = {}, {}, {}
+with use_kernel_backend("pallas"):
+    model = LM(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    for name, m in (("local", None), ("mesh", mesh)):
+        eng = Engine(model, params, batch=4, max_len=32, page_size=8, mesh=m)
+        rids = [eng.submit(p, 4) for p in prompts]
+        toks = jax.ShapeDtypeStruct((4, 1), np.int32)
+        jaxprs[name] = str(jax.make_jaxpr(eng.decode_step)(
+            eng.params, eng.cache, toks))
+        steps = []
+        while not eng.idle:
+            eng.step()
+            if eng.last_decode is not None:
+                steps.append(np.asarray(eng.last_decode[0], np.float32))
+                eng.last_decode = None
+        res = eng.drain()
+        out[name] = [res[r] for r in rids]
+        logits[name] = steps
+err = max(float(np.abs(a - b).max())
+          for a, b in zip(logits["local"], logits["mesh"]))
+print(json.dumps(dict(devices=jax.device_count(), same=out["local"] == out["mesh"],
+                      err=err, steps=len(logits["mesh"]),
+                      local_map="shard_map" in jaxprs["local"],
+                      mesh_map="shard_map" in jaxprs["mesh"])))
+"""
+
+
+def test_mesh_engine_runs_kernels_per_shard_subprocess():
+    """The sharded Engine on the kernel path: every kernel call runs inside
+    shard_map on a multi-device mesh (a compiled Mosaic kernel cannot be
+    partitioned by GSPMD), and serving matches the unsharded Engine."""
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
+    res = subprocess.run([sys.executable, "-c", _ENGINE_SUB],
+                         capture_output=True, text=True, timeout=420, env=env)
+    assert res.returncode == 0, res.stderr[-3000:]
+    rec = json.loads(res.stdout.strip().splitlines()[-1])
+    assert rec["devices"] == 4
+    assert rec["mesh_map"] and not rec["local_map"], rec
+    assert rec["same"], rec
+    assert rec["steps"] > 0 and rec["err"] < 1e-4, rec
+
+
 def test_ring_flash_mesh8_fwd_and_bwd(mesh8):
     """In-process shard_map parity when the CI mesh leg forces 8 devices."""
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -282,7 +344,7 @@ def test_make_shardings_returns_params_shape():
     from repro.parallel.steps import make_shardings
     cfg = reduced(get_config("llama3_2_1b"))
     model = LM(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(data=1, model=1)
     _, _, rules, params_shape = make_shardings(model, mesh)
     want = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     assert jax.tree.map(lambda a, b: (a.shape, a.dtype) == (b.shape, b.dtype),
@@ -298,7 +360,7 @@ def test_serve_step_greedy_routes_through_greedy_step():
     from repro.parallel.steps import build_serve_step
     cfg = reduced(get_config("llama3_2_1b"))
     model = LM(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(data=1, model=1)
     params = model.init(jax.random.PRNGKey(0))
     tokens = jnp.asarray(np.random.RandomState(6).randint(
         0, cfg.vocab_size, (2, 1)))

@@ -134,9 +134,10 @@ import jax, jax.numpy as jnp
 from repro.configs import get_config, reduced
 from repro.models import LM
 from repro.optim import AdamW, WarmupCosine
+from repro.launch.mesh import make_mesh
 from repro.parallel.steps import build_serve_step, build_train_step
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = reduced(get_config("%ARCH%"))
 model = LM(cfg, remat="full")
 bs = {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32)}
@@ -183,9 +184,10 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline import pipeline_apply, split_stages
 
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = make_mesh((4,), ("pipe",))
 L, d, M, mb = 8, 16, 6, 4
 rng = np.random.RandomState(0)
 stacked = {"w": jnp.asarray(rng.randn(L, d, d) * 0.2, jnp.float32),
@@ -261,3 +263,35 @@ def test_mamba2_pallas_kernel_route_matches_ssd():
         got, _ = model.forward(params, tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
+
+
+def test_shard_activation_raises_on_invalid_constraint_with_mesh():
+    """With a mesh in the rules the constraint is applied, so an invalid
+    one is an error instead of a silently dropped placement."""
+    from repro.launch.mesh import make_local_mesh
+    from repro.parallel.context import Rules, shard_activation, use_rules
+
+    mesh = make_local_mesh(data=1, model=1)
+    x = jnp.ones((4,))          # rank 1: the (batch, seq, d) spec cannot fit
+    with use_rules(Rules(batch_axes=("data",), mesh=mesh)):
+        with pytest.raises(ValueError, match="sharding"):
+            shard_activation(x, "act_btd")
+        y = shard_activation(jnp.ones((2, 4, 8)), "act_btd")
+    assert y.shape == (2, 4, 8)
+
+
+def test_kernel_backend_follows_platform_per_op(monkeypatch):
+    """Kernels on a TPU, references elsewhere; an op the TPU compiler
+    refuses (``TPU_REFUSED``) keeps its reference on a TPU too, and a
+    pinned backend wins everywhere."""
+    from repro.layers.common import TPU_REFUSED, kernel_backend
+
+    assert "ssm_scan" in TPU_REFUSED
+    assert kernel_backend() == kernel_backend("ssm_scan") == "jnp"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernel_backend() == kernel_backend("flash_attention") == "pallas"
+    assert kernel_backend("ssm_scan") == "jnp"
+    with use_kernel_backend("pallas"):
+        assert kernel_backend("ssm_scan") == "pallas"
+    with use_kernel_backend("jnp"):
+        assert kernel_backend() == "jnp"

@@ -346,3 +346,41 @@ def test_unpageable_models_raise_on_paged_cache():
         model.init_paged_cache(2, 8, 4, 4)
     with pytest.raises(ValueError, match="pageable"):
         Engine(model, {}, batch=2, max_len=16, page_size=4)
+
+
+def test_engine_surfaces_corrupt_tune_cache(tmp_path, monkeypatch):
+    """A persisted flash_decode winner that cannot build at the engine's
+    shapes is an error at Engine construction — never a silent fallback to
+    a default page size."""
+    import json
+
+    from repro.core.device import default_device
+    from repro.core.tune import SCHEMA_VERSION, tune_cache_key
+    from repro.kernels.flash_attention import flash_decode
+    from repro.launch.tuning import serving_probes
+
+    monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
+    cfg = reduced(get_config("llama3_2_1b"))
+    model = LM(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch, max_len = 2, 32
+    args, probe = serving_probes(cfg, batch, max_len, max_len)["flash_decode"]
+    backend, interpret, rp = flash_decode._resolve(probe)
+    _, defines, _ = flash_decode._prepare(args, rp)
+    dev = default_device(backend, interpret)
+    digest, payload = tune_cache_key("flash_decode", defines,
+                                     flash_decode.sweep, dev.backend,
+                                     dev.interpret)
+    path = tmp_path / "autotune" / f"{digest}.json"
+    path.parent.mkdir(parents=True)
+    path.write_text(json.dumps(dict(payload, schema=SCHEMA_VERSION,
+                                    winner={"block_kv": 0},
+                                    best_seconds=1.0)))
+    before = dict(flash_decode.defaults)
+    try:
+        with pytest.raises(ValueError, match="block must be positive"):
+            Engine(model, params, batch=batch, max_len=max_len)
+        assert flash_decode.defaults == before   # nothing was adopted
+    finally:
+        flash_decode.defaults.clear()
+        flash_decode.defaults.update(before)

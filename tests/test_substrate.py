@@ -227,6 +227,19 @@ def test_watchdog_absolute_deadline():
     assert wd.observe(0, 3.0)
 
 
+def test_make_local_mesh_axes_are_auto():
+    # sharding-in-types (Explicit axes, jax.make_mesh's default) rejects the
+    # layers' GSPMD-style constraints; every repo mesh is Auto
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_local_mesh(model=1)
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert make_mesh((1,), ("pipe",)).axis_types == (AxisType.Auto,)
+
+
 def test_choose_mesh_shape_elastic():
     assert choose_mesh_shape(512, model=16, pod=2) == (2, 16, 16)
     assert choose_mesh_shape(256, model=16) == (16, 16)
@@ -242,7 +255,7 @@ def test_param_specs_divisibility_and_layout():
     cfg = get_config("llama3_2_1b")
     model = LM(cfg)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(data=1, model=1)
 
     class FakeMesh:
         shape = {"data": 16, "model": 16}
