@@ -42,6 +42,16 @@ def _freeze(v):
     return v
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the kernel's name, so its jit is ``jit_<name>`` in
+    traces and compile logs rather than the expansion's ``jit_fn``."""
+    def kernel(*args):
+        return fn(*args)
+
+    kernel.__name__ = kernel.__qualname__ = name
+    return kernel
+
+
 class Device:
     """A compute backend with its own kernel build cache."""
 
@@ -145,7 +155,7 @@ class Device:
         # per build ($REPRO_ANALYZE / set_analysis_mode; "off" skips).
         _analyze.check_built_spec(spec, D, mode=analyze)
         fn = lang.expand(spec, D, self.backend, interpret=self.interpret)
-        kern = Kernel(self, spec, jax.jit(fn), defines)
+        kern = Kernel(self, spec, jax.jit(_named(fn, spec.name)), defines)
 
         with self._lock:
             self._builder_cache(builder)[key] = kern

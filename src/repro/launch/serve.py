@@ -14,6 +14,11 @@ through :func:`repro.launch.tuning.adopt`: any op with a persisted
 the prefill/decode paths pick the TUNED block sizes — and the engine
 adopts ``flash_decode``'s tuned block as its page size.
 
+On the engine path ``prefill_s`` is the admissions' share of the drain,
+read from the engine's own ``engine.admit`` spans (``repro.runtime.spans``).
+The summary line reads the recorder's counters over the call: the engine's
+admissions, retirements, preemptions and tokens, and the programs compiled.
+
   PYTHONPATH=src python -m repro.launch.serve --arch llama3_2_1b --reduced \
       --batch 4 --prompt-len 16 --gen 32
 """
@@ -32,6 +37,7 @@ from repro.models import LM
 from repro.parallel.steps import build_prefill_step, build_serve_step
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
+from repro.runtime import spans
 
 __all__ = ["apply_tuned_winners", "generate", "main"]
 
@@ -88,10 +94,11 @@ def generate(model: LM, params, prompts: np.ndarray, *, gen_tokens: int,
     eng = Engine(model, params, batch=b, max_len=max_len,
                  page_size=page_size, num_pages=num_pages, eos_id=eos_id,
                  greedy=greedy, temperature=temperature, rng=rng, mesh=mesh)
-    t0 = time.time()
+    t0 = time.perf_counter()
     rids = [eng.submit(prompts[i].tolist(), gen_tokens) for i in range(b)]
     results = eng.drain(max_steps=8 * (b * gen_tokens + b))
-    decode_s = time.time() - t0
+    drain_s = time.perf_counter() - t0
+    admit_s = _admit_seconds(eng.engine_id)
     pad = _pad_token(eos_id, pad_id)
     rows = [results[r] for r in rids]
     width = (max(len(r) for r in rows)
@@ -103,10 +110,19 @@ def generate(model: LM, params, prompts: np.ndarray, *, gen_tokens: int,
         out[i, :len(r)] = r
         n_gen += len(r)
     preempted = sum(req.preempted for req in eng._requests.values())
-    return out, {"prefill_s": 0.0, "decode_s": decode_s,
-                 "tokens_per_s": n_gen / max(decode_s, 1e-9),
+    return out, {"prefill_s": admit_s, "decode_s": drain_s - admit_s,
+                 "tokens_per_s": n_gen / max(drain_s, 1e-9),
                  "tuned": {}, "engine": True, "preempted": preempted,
                  "page_size": eng.page_size}
+
+
+def _admit_seconds(engine_id: int) -> float:
+    """Seconds one engine spent admitting (prefill, KV scatter, first
+    token), from its ``engine.admit`` spans still in the recorder's ring
+    (the newest ``spans.RING`` records)."""
+    return sum(r["end_ns"] - r["start_ns"] for r in spans.records()
+               if r["name"] == "engine.admit"
+               and r["attrs"].get("engine") == engine_id) / 1e9
 
 
 def _generate_static(model: LM, params, prompts: np.ndarray, *,
@@ -140,15 +156,15 @@ def _generate_static(model: LM, params, prompts: np.ndarray, *,
     serve_fn, sh = build_serve_step(model, mesh, batch=b, max_len=max_len,
                                     greedy=greedy)
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     logits, cache = prefill_fn(params, {"tokens": jnp.asarray(prompts)})
-    cache = jax.device_put(cache, sh["cache"])
-    prefill_s = time.time() - t0
+    cache = jax.block_until_ready(jax.device_put(cache, sh["cache"]))
+    prefill_s = time.perf_counter() - t0
 
     out = np.zeros((b, gen_tokens), np.int32)
     done = np.zeros((b,), bool)
     tok = np.asarray(model.greedy_token(logits))
-    t0 = time.time()
+    t0 = time.perf_counter()
     for t in range(gen_tokens):
         out[:, t] = np.where(done, pad, tok)
         if eos_id is not None:
@@ -165,7 +181,7 @@ def _generate_static(model: LM, params, prompts: np.ndarray, *,
             rng, sub = jax.random.split(rng)
             tok = np.asarray(jax.random.categorical(
                 sub, logits[..., :cfg.vocab_size] / temperature))
-    decode_s = time.time() - t0
+    decode_s = time.perf_counter() - t0
     n_gen = out.shape[1] * b
     return out, {"prefill_s": prefill_s, "decode_s": decode_s,
                  "tokens_per_s": n_gen / max(decode_s, 1e-9),
@@ -198,14 +214,24 @@ def main(argv=None):
     params = model.init(jax.random.PRNGKey(args.seed))
     prompts = np.random.RandomState(args.seed).randint(
         0, cfg.vocab_size, (args.batch, args.prompt_len)).astype(np.int32)
+    before = spans.counters()
     out, stats = generate(model, params, prompts, gen_tokens=args.gen,
                           engine=args.engine)
+    ran = {k: v - before.get(k, 0) for k, v in spans.counters().items()}
     if stats.get("tuned"):
         print(f"[serve] adopted persisted tune winners: {stats['tuned']}")
     path = "paged-engine" if stats["engine"] else "static"
     print(f"[serve] {path} batch={args.batch} prompt={args.prompt_len} "
           f"gen={out.shape[1]}: prefill {stats['prefill_s']:.2f}s, "
           f"{stats['tokens_per_s']:.1f} tok/s decode")
+    if stats["engine"]:
+        print(f"[serve] engine: {ran.get('engine.admitted', 0)} admissions, "
+              f"{ran.get('engine.retired', 0)} retirements, "
+              f"{ran.get('engine.preempted', 0)} preemptions, "
+              f"{ran.get('engine.tokens', 0)} tokens")
+    compiled = sorted(k[len("compile."):] for k, n in ran.items()
+                      if k.startswith("compile.") and n)
+    print(f"[serve] compiled: {', '.join(compiled) or 'nothing'}")
     print("[serve] first row:", out[0, :16].tolist())
     return out
 

@@ -17,9 +17,24 @@ the block-table tile (``flash_decode_paged``), which is bit-identical to
 contiguous ``flash_decode`` when the page size equals its block size — so
 a greedy Engine run reproduces the static per-sequence baseline token for
 token.
+
+Every step records host spans (``repro.runtime.spans``), each carrying the
+engine's process-unique ``engine`` id: ``engine.step`` around the whole
+step; in it ``engine.admit`` per admission (``rid``) with its
+``engine.prefill`` and ``engine.scatter`` dispatches and the blocking
+``engine.first_token`` fetch, ``engine.grow`` (growth and preemption),
+``engine.decode`` (the decode dispatch, with ``running``, ``pages_used``
+and ``pages_total``), ``engine.fetch`` (the wait for the next tokens) and
+``engine.emit``. ``engine.queue`` (async, ``rid``) runs from submission,
+or from a preemption, to the start of the admission. Counters:
+``engine.tokens`` here, ``engine.admitted`` / ``retired`` / ``preempted``
+in the scheduler. The jitted programs carry stable names
+(``jit_engine_decode``, ``jit_engine_prefill``, ...).
 """
 
 from __future__ import annotations
+
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -27,10 +42,13 @@ import numpy as np
 
 from repro.core.device import fit_block
 from repro.parallel.context import use_rules
+from repro.runtime import spans
 
 from .scheduler import Scheduler
 
 __all__ = ["Engine"]
+
+_ENGINE_IDS = itertools.count(1)
 
 
 class Engine:
@@ -50,6 +68,7 @@ class Engine:
         self.greedy = greedy
         self.temperature = float(temperature)
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
+        self.engine_id = next(_ENGINE_IDS)      # the ``engine`` span attribute
         if page_size is None:
             # the page size IS flash_decode's tuned block size: paged blocks
             # then stream identically to contiguous ones (and bit-identically
@@ -76,30 +95,37 @@ class Engine:
         self.cache = model.init_paged_cache(batch, num_pages, self.page_size,
                                             nsp, dtype=cache_dtype)
         self._requests = {}
+        self._queued = {}                      # rid -> open engine.queue span
         self.last_decode = None
         self._pending = np.zeros((batch,), np.int32)
         self._slot_pages = [[] for _ in range(batch)]
         rules = None
         if mesh is not None:
             from repro.parallel.steps import build_paged_serve_step
-            self._step_fn, specs = build_paged_serve_step(
+            mesh_step, specs = build_paged_serve_step(
                 model, mesh, batch=batch, greedy=greedy)
             self.params = jax.device_put(params, specs["params"])
             self.cache = jax.device_put(self.cache, specs["cache"])
             rules = specs["rules"]
+
+            def engine_decode(p, c, t):
+                return mesh_step(p, c, t)
         else:
             self.params = params
             fn = model.paged_greedy_step if greedy else model.paged_decode_step
-            self._step_fn = jax.jit(lambda p, c, t: fn(p, t, c),
-                                    donate_argnums=(1,))
 
-        def _prefill(p, t):
+            def engine_decode(p, c, t):
+                return fn(p, t, c)
+
+        self._step_fn = jax.jit(engine_decode, donate_argnums=(1,))
+
+        def engine_prefill(p, t):
             # on a mesh, under the step's rules: the kernels then run per
             # shard (``shard_kernel``) like the decode step's
             with use_rules(rules):
                 return model.prefill(p, t)
 
-        self._prefill_fn = jax.jit(_prefill)
+        self._prefill_fn = jax.jit(engine_prefill)
 
         # admission scatter, fused: ALL stacks' pages + pos rows land in one
         # jitted call (the eager .at[].set chain was ~10 dispatches per
@@ -110,8 +136,8 @@ class Engine:
         # step re-pins to -1 anyway.
         pg = self.page_size
 
-        def _scatter_impl(stacks, pos_pages, table, lens, pstacks, pages,
-                          slot):
+        def engine_scatter(stacks, pos_pages, table, lens, pstacks, pages,
+                           slot):
             nsp_ = pages.shape[0]
             out = []
             for sc, pc in zip(stacks, pstacks):
@@ -135,32 +161,38 @@ class Engine:
             return (out, pos_pages.at[pages].set(rows),
                     table.at[slot].set(pages), lens.at[slot].set(plen))
 
-        self._scatter_fn = jax.jit(_scatter_impl,
+        self._scatter_fn = jax.jit(engine_scatter,
                                    donate_argnums=(0, 1, 2, 3))
 
         # retirement + growth are tiny table/pos edits — still worth one
         # jitted call each instead of an eager dispatch chain
         nsp_t = self.sched.nseq_pages
 
-        def _clear_impl(table, lens, slot):
+        def engine_clear(table, lens, slot):
             return (table.at[slot].set(jnp.zeros((nsp_t,), jnp.int32)),
                     lens.at[slot].set(0))
 
-        self._clear_fn = jax.jit(_clear_impl, donate_argnums=(0, 1))
+        self._clear_fn = jax.jit(engine_clear, donate_argnums=(0, 1))
 
-        def _grow_impl(pos_pages, table, pages, new, slot):
+        def engine_grow(pos_pages, table, pages, new, slot):
             cur = pos_pages[pages]                 # (nsp, pg); dup page-0
             rows = jnp.where(new[:, None], -1, cur)  # reads write back as-is
             return pos_pages.at[pages].set(rows), table.at[slot].set(pages)
 
-        self._grow_fn = jax.jit(_grow_impl, donate_argnums=(0, 1))
-        self._greedy_fn = jax.jit(model.greedy_token)
+        self._grow_fn = jax.jit(engine_grow, donate_argnums=(0, 1))
+
+        def engine_first_token(logits):
+            return model.greedy_token(logits)
+
+        self._greedy_fn = jax.jit(engine_first_token)
 
     # -------------------------------------------------------------- requests
     def submit(self, prompt, max_new: int) -> int:
         """Queue a prompt for generation. Returns the request id."""
         rid = self.sched.submit(prompt, max_new)
         self._requests[rid] = self.sched.queue[-1]
+        self._queued[rid] = spans.begin("engine.queue",
+                                        engine=self.engine_id, rid=rid)
         return rid
 
     def result(self, rid: int) -> list[int]:
@@ -192,7 +224,7 @@ class Engine:
     def _scatter_prefill(self, pcache, pages: list[int], slot: int):
         """Copy a B=1 contiguous prefill cache into the sequence's pages
         (logical page j -> pool page pages[j]), stamp their pos rows and the
-        slot's table/len — one jitted call (see ``_scatter_impl``)."""
+        slot's table/len — one jitted call (see ``engine_scatter``)."""
         c = self.cache
         (c["stacks"], c["pos_pages"], c["table"], c["len"]) = \
             self._scatter_fn(c["stacks"], c["pos_pages"], c["table"],
@@ -225,6 +257,7 @@ class Engine:
     def _emit(self, slot: int, tok: int, emitted: dict):
         req = self.sched.slots[slot]
         req.tokens.append(tok)
+        spans.count("engine.tokens")
         emitted.setdefault(req.rid, []).append(tok)
         if ((self.eos_id is not None and tok == self.eos_id)
                 or len(req.tokens) >= req.max_new):
@@ -232,36 +265,51 @@ class Engine:
             self._clear_slot(slot)
 
     def _admit(self, slot: int, req, emitted: dict):
-        resume = req.resume_prompt             # prompt + generated-so-far
-        toks = jnp.asarray(np.asarray(resume, np.int32)[None])
-        logits, pcache = self._prefill_fn(self.params, toks)
-        pages = self.sched.pages.owned(req.rid)
-        self._scatter_prefill(pcache, pages, slot)
-        if self.greedy:
-            tok = int(np.asarray(self._greedy_fn(logits[0])))
-        else:
-            tok = int(self._sample(np.asarray(logits))[0])
-        self._pending[slot] = tok
-        self._emit(slot, tok, emitted)
+        spans.end(self._queued.pop(req.rid, None))
+        eid = self.engine_id
+        with spans.span("engine.admit", engine=eid, rid=req.rid):
+            resume = req.resume_prompt         # prompt + generated-so-far
+            toks = jnp.asarray(np.asarray(resume, np.int32)[None])
+            with spans.span("engine.prefill", engine=eid):
+                logits, pcache = self._prefill_fn(self.params, toks)
+            pages = self.sched.pages.owned(req.rid)
+            with spans.span("engine.scatter", engine=eid):
+                self._scatter_prefill(pcache, pages, slot)
+            with spans.span("engine.first_token", engine=eid):
+                if self.greedy:
+                    tok = int(np.asarray(self._greedy_fn(logits[0])))
+                else:
+                    tok = int(self._sample(np.asarray(logits))[0])
+            self._pending[slot] = tok
+            self._emit(slot, tok, emitted)
 
     def step(self) -> dict:
         """One engine step: retirement happened at the previous emission;
         admit queued requests into free slots, grow (preempting on famine),
         run ONE batched decode step, emit. Returns ``{rid: [tokens]}``
         emitted this step (admissions emit their prefill token here too)."""
+        with spans.span("engine.step", engine=self.engine_id):
+            return self._step()
+
+    def _step(self) -> dict:
+        eid = self.engine_id
         emitted: dict = {}
         for slot, req in self.sched.admit():
             self._admit(slot, req, emitted)
-        for slot in list(self.sched.running):
-            if self.sched.slots[slot] is None:
-                continue                        # evicted by a younger grow
-            while not self.sched.grow(slot):
-                freed = self.sched.preempt_youngest(exclude=slot)
-                if freed is None:
-                    raise RuntimeError(
-                        "page pool cannot hold a single sequence")
-                self._clear_slot(freed)
-            self._sync_grown(slot)
+        with spans.span("engine.grow", engine=eid):
+            for slot in list(self.sched.running):
+                if self.sched.slots[slot] is None:
+                    continue                    # evicted by a younger grow
+                while not self.sched.grow(slot):
+                    freed = self.sched.preempt_youngest(exclude=slot)
+                    if freed is None:
+                        raise RuntimeError(
+                            "page pool cannot hold a single sequence")
+                    self._clear_slot(freed)
+                    rid = self.sched.queue[0].rid   # requeued at the front
+                    self._queued[rid] = spans.begin(
+                        "engine.queue", engine=eid, rid=rid)
+                self._sync_grown(slot)
         running = self.sched.running
         if not running:
             if self.sched.queue:
@@ -269,22 +317,29 @@ class Engine:
                     "no slot admitted but requests remain queued — page "
                     "pool too small for the front request")
             return emitted
-        toks = jnp.asarray(self._pending.reshape(-1, 1))
-        if self.greedy:
-            nxt, logits, self.cache = self._step_fn(self.params, self.cache,
-                                                    toks)
-            nxt = np.asarray(nxt)
-        else:
-            logits, self.cache = self._step_fn(self.params, self.cache, toks)
-            nxt = self._sample(np.asarray(logits))
+        pool = self.sched.pages
+        with spans.span("engine.decode", engine=eid, running=len(running),
+                        pages_used=pool.num_pages - 1 - pool.free_pages,
+                        pages_total=pool.num_pages - 1):
+            toks = jnp.asarray(self._pending.reshape(-1, 1))
+            if self.greedy:
+                nxt, logits, self.cache = self._step_fn(
+                    self.params, self.cache, toks)
+            else:
+                logits, self.cache = self._step_fn(self.params, self.cache,
+                                                   toks)
+        with spans.span("engine.fetch", engine=eid):
+            nxt = (np.asarray(nxt) if self.greedy
+                   else self._sample(np.asarray(logits)))
         # this step's (batch, Vpad) logits, on device, and which request
         # each running slot held — for callers that inspect the decode
         self.last_decode = (
             logits, {self.sched.slots[s].rid: s for s in running})
-        for slot in running:
-            tok = int(nxt[slot])
-            self._pending[slot] = tok
-            self._emit(slot, tok, emitted)
+        with spans.span("engine.emit", engine=eid):
+            for slot in running:
+                tok = int(nxt[slot])
+                self._pending[slot] = tok
+                self._emit(slot, tok, emitted)
         return emitted
 
     def drain(self, max_steps: int | None = None) -> dict:

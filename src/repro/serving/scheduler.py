@@ -8,12 +8,17 @@ into free slots (FIFO), ``grow`` every running slot whose next token starts
 a new page — preempting the YOUNGEST running sequences when the pool runs
 dry (they requeue at the FRONT with their generated prefix and re-prefill
 on re-admission, so no work is lost and older sequences never starve).
+
+It counts ``engine.admitted``, ``engine.retired`` and ``engine.preempted``
+in ``repro.runtime.spans``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from collections import deque
+
+from repro.runtime import spans
 
 from .pages import PageAllocator
 
@@ -81,6 +86,7 @@ class Scheduler:
         self.pages.release(req.rid)
         req.state = "done"
         self.slots[slot] = None
+        spans.count("engine.retired")
         return req
 
     def admit(self) -> list[tuple[int, Request]]:
@@ -102,6 +108,7 @@ class Scheduler:
             self._admit_order += 1
             self._slot_age[slot] = self._admit_order
             placed.append((slot, req))
+        spans.count("engine.admitted", len(placed))
         return placed
 
     def grow(self, slot: int) -> bool:
@@ -133,4 +140,5 @@ class Scheduler:
         req.preempted += 1
         self.slots[slot] = None
         self.queue.appendleft(req)
+        spans.count("engine.preempted")
         return slot
