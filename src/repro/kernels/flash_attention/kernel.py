@@ -508,9 +508,9 @@ def flash_decode_builder(D):
         body=body)
 
 def paged_decode_builder(D):
-    """q: (b, h, 1, d) vs a PAGED cache pool k: (P, hk, page, d),
-    v: (P, hk, page, dv), block_table: (b, NP) i32, kv_len: (b, 1) i32,
-    pos_pages: (P, 1, page) i32 -> o: (b, h, 1, dv).
+    """q: (b, h, 1, d) vs a PAGED cache pool k: (KP, hk, page, d),
+    v: (KP, hk, page, dv), block_table: (b, NP) i32, kv_table: (b, NP) i32,
+    kv_len: (b, 1) i32, pos_pages: (P, 1, page) i32 -> o: (b, h, 1, dv).
 
     The continuous-batching decode kernel (vLLM's PagedAttention idiom
     through the unified language): each sequence owns a per-slot list of
@@ -532,17 +532,26 @@ def paged_decode_builder(D):
     The ``cell_when`` whole-block skip is the contiguous kernel's, applied
     per sequence: while un-wrapped (``kv_len <= capacity``) logical page
     ``j`` holds positions ``[j*page, (j+1)*page)``; never-allocated tail
-    pages point at the engine's null page, whose positions are all ``-1``."""
+    pages point at the engine's null page, whose positions are all ``-1``.
+
+    K and V gather through ``kv_table``, positions through ``block_table``.
+    For a single-layer pool they are the same table (``KP == P``). A model
+    whose pools are stacked by layer, ``(L, P, hk, page, d)``, passes the
+    stack flattened to ``(L*P, ...)`` (a bitcast) with ``kv_table =
+    block_table + l*P``: the kernel reads layer ``l``'s pages where they
+    lie, while positions, shared by every layer, stay per pool page."""
     b, h, hk = D.b, D.h, D.hk
     d, dv = D.d, D.dv
     npages, page, nsp = D.npages, D.page, D.nseq_pages
+    kv_pages = D.kv_pages
     window = D.window
     sm_scale = D.sm_scale
     g = h // hk
     cap = nsp * page                       # per-sequence slot capacity
     dtype = jnp.dtype(D.dtype)
 
-    def body(ctx, q_ref, k_ref, v_ref, tab_ref, len_ref, sp_ref, o_ref):
+    def body(ctx, q_ref, k_ref, v_ref, tab_ref, kvtab_ref, len_ref, sp_ref,
+             o_ref):
         m_scr, l_scr, acc_scr = ctx.scratch
         j = ctx.reduce_id(0)
 
@@ -600,15 +609,18 @@ def paged_decode_builder(D):
         inputs=[
             Tile("q", (b, h, 1, d), dtype, block=(1, 1, 1, d),
                  index=lambda b_, h_, j: (b_, h_, 0, 0)),
-            # pool page axis: dynamic, read from the block table per cell
+            # pool page axis: dynamic, read from the K/V table per cell
             # (the static map's 0 there is the ignored placeholder)
-            Tile("k", (npages, hk, page, d), dtype, block=(1, 1, page, d),
+            Tile("k", (kv_pages, hk, page, d), dtype, block=(1, 1, page, d),
                  index=lambda b_, h_, j: (0, h_ // g, 0, 0),
-                 index_tile=("block_table", 0)),
-            Tile("v", (npages, hk, page, dv), dtype, block=(1, 1, page, dv),
+                 index_tile=("kv_table", 0)),
+            Tile("v", (kv_pages, hk, page, dv), dtype,
+                 block=(1, 1, page, dv),
                  index=lambda b_, h_, j: (0, h_ // g, 0, 0),
-                 index_tile=("block_table", 0)),
+                 index_tile=("kv_table", 0)),
             Tile("block_table", (b, nsp), jnp.int32, block=(1, 1),
+                 index=lambda b_, h_, j: (b_, j)),
+            Tile("kv_table", (b, nsp), jnp.int32, block=(1, 1),
                  index=lambda b_, h_, j: (b_, j)),
             Tile("kv_len", (b, 1), jnp.int32, block=(1, 1),
                  index=lambda b_, h_, j: (b_, 0)),

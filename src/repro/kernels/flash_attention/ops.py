@@ -20,7 +20,10 @@ POOL of fixed-size pages shared by every sequence, and a per-sequence
 tile-indexed index map (``Tile(index_tile=...)``) — the kernel's K/V index
 maps read the table at runtime to gather non-contiguous pages, on every
 backend, with the indirection analyzer-bounds-checked (``BOUNDS_TABLE``)
-and cost-priced as a gather. ``paged_decode_attention`` is its wrapper.
+and cost-priced as a gather. A second table, ``kv_table``, addresses K/V
+alone, so a model's layer-stacked pool is read in place (layer ``l`` at
+``block_table + l*P`` of the flattened stack) while positions stay per
+pool page. ``paged_decode_attention`` is its wrapper.
 There is no kernel-side tuning knob: the block size IS the page size, a
 property of the pool layout the serving engine owns (it adopts
 ``flash_decode``'s tuned ``block_kv`` winner as its page size).
@@ -294,6 +297,9 @@ def _paged_pre(args, params):
         table = table[None]
     nsp = table.shape[-1]
     table = table.reshape(b, nsp)
+    kv_table = params.get("kv_table")
+    kv_table = table if kv_table is None else \
+        jnp.asarray(kv_table, jnp.int32).reshape(b, nsp)
     kv_len = params.get("kv_len")
     if kv_len is None:
         kv_len = nsp * page                  # full logical capacity valid
@@ -315,17 +321,19 @@ def _paged_pre(args, params):
             mode="drop")
     # the kernel's tile is (npages, 1, page): its (1, page) block then spans
     # the full trailing dims, as the TPU's block-shape rule requires
-    pos = jnp.asarray(pos, jnp.int32).reshape(npages, 1, page)
-    return q, k, v, table, kv_len, pos
+    pos = jnp.asarray(pos, jnp.int32)
+    pos = pos.reshape(pos.shape[0], 1, page)
+    return q, k, v, table, kv_table, kv_len, pos
 
 
 def _paged_defines(args, params):
-    q, k, v, table, kv_len, pos = args
+    q, k, v, table, kv_table, kv_len, pos = args
     b, h, one, d = q.shape
     if one != 1:
         raise ValueError(f"flash_decode_paged: expected a single query token, "
                          f"got q of shape {q.shape}")
-    npages, hk, page, _ = k.shape
+    kv_pages, hk, page, _ = k.shape
+    npages = pos.shape[0]
     dv = v.shape[-1]
     if h % hk:
         raise ValueError(f"flash_decode_paged: {h} query heads not a multiple "
@@ -333,30 +341,32 @@ def _paged_defines(args, params):
     if q.dtype != k.dtype or q.dtype != v.dtype:
         raise ValueError(f"flash_decode_paged: dtypes disagree "
                          f"({q.dtype}/{k.dtype}/{v.dtype})")
-    if tuple(v.shape[:3]) != (npages, hk, page):
+    if tuple(v.shape[:3]) != (kv_pages, hk, page):
         raise ValueError(f"flash_decode_paged: v pool shape {v.shape} does "
                          f"not match k pool {k.shape}")
     nsp = table.shape[-1]
-    if tuple(pos.shape) != (npages, 1, page):
+    stacked = params.get("kv_table") is not None
+    if (kv_pages % npages) if stacked else (kv_pages != npages):
         raise ValueError(f"flash_decode_paged: pos_pages shape {pos.shape} "
-                         f"does not match the pool ({npages} pages of "
+                         f"does not match the pool ({kv_pages} pages of "
                          f"{page} slots)")
     sm_scale = params["sm_scale"]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     window = params["window"]
     return dict(
-        b=b, h=h, hk=hk, d=d, dv=dv, npages=npages, page=page,
-        nseq_pages=nsp,
+        b=b, h=h, hk=hk, d=d, dv=dv, npages=npages, kv_pages=kv_pages,
+        page=page, nseq_pages=nsp,
         window=None if window is None else int(window),
         sm_scale=float(sm_scale),
         dtype=jnp.dtype(q.dtype).name)
 
 
 def _paged_tune_ref(args, params):
-    q, k, v, table, kv_len, pos = args
-    return paged_decode_ref(q, k, v, block_table=table, kv_len=kv_len,
-                            pos_pages=pos, window=params["window"],
+    q, k, v, table, kv_table, kv_len, pos = args
+    return paged_decode_ref(q, k, v, block_table=table, kv_table=kv_table,
+                            kv_len=kv_len, pos_pages=pos,
+                            window=params["window"],
                             sm_scale=params["sm_scale"])
 
 
@@ -377,9 +387,10 @@ flash_decode_paged = define_op(
     derive_defines=_paged_defines,
     pre=_paged_pre,
     defaults=dict(window=None, sm_scale=None),
-    array_params=("block_table", "kv_len", "pos_pages"),
+    array_params=("block_table", "kv_table", "kv_len", "pos_pages"),
     # the array params ride ref_params too: the oracle needs the table
-    ref_params=("window", "sm_scale", "block_table", "kv_len", "pos_pages"),
+    ref_params=("window", "sm_scale", "block_table", "kv_table", "kv_len",
+                "pos_pages"),
     tune_ref=_paged_tune_ref,
     sweep=dict(),             # the page size IS the block size (pool layout)
     example=_paged_example,
@@ -389,21 +400,27 @@ flash_decode_paged = define_op(
     runtime (a tile-indexed index map — no contiguous copy on any backend).
     ``kv_len`` ((B,) i32) is per-sequence; ``pos_pages`` ((P,page) i32, -1 =
     empty) gives pool slots' absolute positions for rotated-window layouts;
-    omitted, logical order is positional.""",
+    omitted, logical order is positional. ``kv_table`` ((B,n_seq_pages) i32,
+    default ``block_table``) is the table K/V read through: with pools
+    stacked by layer and flattened to (L*P,...), ``block_table + l*P`` reads
+    layer l's pages in place, while ``pos_pages`` stays (P,page) and is read
+    through ``block_table``.""",
 )
 
 
-def paged_decode_attention(q, k_pages, v_pages, *, block_table, kv_len=None,
-                           pos_pages=None, window=None, sm_scale=None,
-                           backend="auto", interpret=None):
+def paged_decode_attention(q, k_pages, v_pages, *, block_table, kv_table=None,
+                           kv_len=None, pos_pages=None, window=None,
+                           sm_scale=None, backend="auto", interpret=None):
     """Paged decode attention over a shared KV page pool (no grad).
 
     The serving-engine hot path: each sequence reads its KV through its
     ``block_table`` row, so mixed-length continuous batches share one pool
-    with zero copying (see ``flash_decode_paged``)."""
+    with zero copying (see ``flash_decode_paged``). A layer-stacked pool is
+    read where it lies: flatten it to ``(L*P, ...)`` and pass
+    ``kv_table=block_table + l*P``."""
     return flash_decode_paged(
-        q, k_pages, v_pages, block_table=block_table, kv_len=kv_len,
-        pos_pages=pos_pages, window=window, sm_scale=sm_scale,
+        q, k_pages, v_pages, block_table=block_table, kv_table=kv_table,
+        kv_len=kv_len, pos_pages=pos_pages, window=window, sm_scale=sm_scale,
         backend=backend, interpret=interpret)
 
 

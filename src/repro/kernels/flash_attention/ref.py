@@ -157,8 +157,8 @@ def decode_ref(q, k, v, *, window=None, sm_scale=None, kv_len=None,
     return o.reshape(b, h, 1, dv).astype(q.dtype)
 
 
-def paged_decode_ref(q, k_pages, v_pages, *, block_table, kv_len=None,
-                     pos_pages=None, window=None, sm_scale=None):
+def paged_decode_ref(q, k_pages, v_pages, *, block_table, kv_table=None,
+                     kv_len=None, pos_pages=None, window=None, sm_scale=None):
     """Paged single-token decode oracle: q (B, H, 1, D) against page POOLS.
 
     The cache is a pool of fixed-size pages shared by every sequence —
@@ -168,6 +168,9 @@ def paged_decode_ref(q, k_pages, v_pages, *, block_table, kv_len=None,
     ``kv_len`` ((B,) or (B, 1) i32) is each sequence's valid prefix length;
     ``pos_pages`` ((P, page) i32, -1 = empty) gives each pool slot's absolute
     position (rotated-window layouts); omitted, logical order is positional.
+    ``kv_table`` (default ``block_table``) is the table the pools are read
+    through: a layer-stacked pool flattened to (L*P, ...) is read at
+    ``block_table + l*P``, while ``pos_pages`` stays per pool page (P, page).
     This is the function ``flash_decode_paged`` computes; per-sequence it
     equals ``decode_ref`` on the gathered contiguous cache."""
     b, h, _, d = q.shape
@@ -185,9 +188,11 @@ def paged_decode_ref(q, k_pages, v_pages, *, block_table, kv_len=None,
     if n.shape[0] == 1:
         n = jnp.broadcast_to(n, (b,))
     n = n.reshape(b)
+    kvtab = tab if kv_table is None else \
+        jnp.asarray(kv_table, jnp.int32).reshape(b, nsp)
     # gather each sequence's pages into logical-contiguous (B, Hk, m, D)
-    kb = jnp.moveaxis(k_pages[tab], 2, 1).reshape(b, hk, m, d)
-    vb = jnp.moveaxis(v_pages[tab], 2, 1).reshape(b, hk, m, dv)
+    kb = jnp.moveaxis(k_pages[kvtab], 2, 1).reshape(b, hk, m, d)
+    vb = jnp.moveaxis(v_pages[kvtab], 2, 1).reshape(b, hk, m, dv)
     if pos_pages is None:
         sp = jnp.broadcast_to(jnp.arange(m, dtype=jnp.int32), (b, m))
     else:

@@ -228,19 +228,27 @@ def gqa_paged_cache_init(cfg, num_pages, page_size, dtype):
     }
 
 
-def gqa_paged_decode(params, x, cache, cfg, *, table, lens, pos_pages,
+def gqa_paged_decode(params, x, cache, cfg, *, layer, table, lens, pos_pages,
                      page_ids, offs):
-    """One-token decode over a PAGED cache. x: (B, 1, d_model).
+    """One-token decode of layer ``layer`` over a PAGED cache.
+    x: (B, 1, d_model).
 
-    The KV pools are shared by every sequence; ``table`` ((B, n_seq_pages)
-    i32) names each sequence's pages in logical order, ``lens`` ((B,) i32)
-    its current length (the new token's position), ``pos_pages`` ((P, page)
-    i32) the pool-slot -> absolute-position map (already including the new
-    token), and ``page_ids``/``offs`` ((B,) each) the pool coordinates of
-    the write — derived once per step by the model, not per layer. Returns
-    (y, new {kp, vp}); attention reads KV exclusively through the block
-    table (``flash_decode_paged``'s tile-indexed index maps — no contiguous
-    gather on any backend)."""
+    ``cache`` holds the KV pools of every layer of the stack, stacked by
+    layer: {kp, vp} of (L, P, Hk, page, hd). The pools are shared by every
+    sequence; ``table`` ((B, n_seq_pages) i32) names each sequence's pages
+    in logical order, ``lens`` ((B,) i32) its current length (the new
+    token's position), ``pos_pages`` ((P, page) i32) the pool-slot ->
+    absolute-position map (already including the new token, shared by all
+    layers), and ``page_ids``/``offs`` ((B,) each) the pool coordinates of
+    the write — derived once per step by the model, not per layer.
+
+    The pools are carried, not sliced: the new token's K/V land with one
+    scatter at ``[layer, page_ids, :, offs]``, which XLA does in place when
+    the caller carries the pools through its layer loop, and attention reads
+    layer ``layer``'s pages where they lie — the stack flattened to
+    (L*P, ...) (a bitcast) and read through ``table + layer*P``
+    (``flash_decode_paged``'s ``kv_table``; no gather on any backend but
+    the XLA reference's). Returns (y, the updated stacked {kp, vp})."""
     b = x.shape[0]
     hd = cfg.resolved_head_dim
     q, k1, v1 = _qkv(params, x, cfg)
@@ -249,22 +257,32 @@ def gqa_paged_decode(params, x, cache, cfg, *, table, lens, pos_pages,
         q = apply_rope(q, p, cfg.rope_theta)
         k1 = apply_rope(k1, p, cfg.rope_theta)
     kp, vp = cache["kp"], cache["vp"]
-    kp = kp.at[page_ids, :, offs].set(k1[:, :, 0].astype(kp.dtype))
-    vp = vp.at[page_ids, :, offs].set(v1[:, :, 0].astype(vp.dtype))
+    n_layers, npages, hk = kp.shape[:3]
+    # one row of hd per (sequence, kv head): the scatter's window is then
+    # the pools' minor dim, so XLA keeps their layout (a window over heads
+    # too would relayout — copy — the whole pool around the scatter)
+    at = (layer, page_ids[:, None], jnp.arange(hk)[None, :], offs[:, None])
+    kp = kp.at[at].set(k1[:, :, 0].astype(kp.dtype))
+    vp = vp.at[at].set(v1[:, :, 0].astype(vp.dtype))
+    kflat = kp.reshape(n_layers * npages, *kp.shape[2:])
+    vflat = vp.reshape(n_layers * npages, *vp.shape[2:])
+    kv_table = table + layer * npages
     kv_len = lens + 1
     if kernel_backend() == "pallas":
         # the pools have no batch dim: every shard holds all pages of its
         # kv heads, and the control state replicates
         o = shard_kernel(
-            lambda q, kp, vp, t, n, pp: paged_decode_attention(
-                q, kp, vp, block_table=t, kv_len=n, pos_pages=pp,
-                window=cfg.window if cfg.window else None,
+            lambda q, kp, vp, t, kt, n, pp: paged_decode_attention(
+                q, kp, vp, block_table=t, kv_table=kt, kv_len=n,
+                pos_pages=pp, window=cfg.window if cfg.window else None,
                 sm_scale=hd ** -0.5),
-            (q, kp, vp, table, kv_len, pos_pages),
-            (_HEADS, _HEADS, _HEADS, (None, None), (None,), (None, None)),
+            (q, kflat, vflat, table, kv_table, kv_len, pos_pages),
+            (_HEADS, _HEADS, _HEADS, (None, None), (None, None), (None,),
+             (None, None)),
             _HEADS)
     else:
-        o = paged_decode_ref(q, kp, vp, block_table=table, kv_len=kv_len,
+        o = paged_decode_ref(q, kflat, vflat, block_table=table,
+                             kv_table=kv_table, kv_len=kv_len,
                              pos_pages=pos_pages,
                              window=cfg.window if cfg.window else None,
                              sm_scale=hd ** -0.5)

@@ -117,12 +117,13 @@ def tblock_decode(params, x, cache, cfg, *, moe=False, dispatch="einsum"):
 
 
 def tblock_paged_decode(params, x, cache, cfg, *, moe=False, dispatch="einsum",
-                        table, lens, pos_pages, page_ids, offs):
-    """``tblock_decode`` over a paged KV pool (GQA only — MLA's latent cache
-    is gated off upstream by ``LM.init_paged_cache``)."""
+                        layer, table, lens, pos_pages, page_ids, offs):
+    """``tblock_decode`` of layer ``layer`` over the layer-stacked paged KV
+    pools (GQA only — MLA's latent cache is gated off upstream by
+    ``LM.init_paged_cache``)."""
     h = rmsnorm(x, params["norm1"], eps=cfg.norm_eps)
     a, cache = attn.gqa_paged_decode(params["attn"], h, cache, cfg,
-                                     table=table, lens=lens,
+                                     layer=layer, table=table, lens=lens,
                                      pos_pages=pos_pages,
                                      page_ids=page_ids, offs=offs)
     x = x + a

@@ -573,7 +573,15 @@ class LM:
     def _paged_decode_hidden(self, params, tokens, cache):
         """One paged decode step up to the final norm. Every slot decodes
         every step — idle slots carry len 0 and a zero block table, writing
-        into and reading from the null page (their output is ignored)."""
+        into and reading from the null page (their output is ignored).
+
+        Each stack's layer-stacked pools (L, P, hk, page, hd) are CARRIED
+        through its layer loop, never sliced per layer or restacked: layer
+        ``l`` scatters its token's K/V at ``[l, page_ids, :, offs]`` of the
+        carry (in place — the cache is donated) and reads its pages from
+        the stack where they lie (``gqa_paged_decode``). The carry out of
+        the loop is the new cache, so the step moves no pool bytes beyond
+        the new tokens and the live pages attention reads."""
         cfg = self.cfg
         table, lens = cache["table"], cache["len"]
         pos_pages = cache["pos_pages"]
@@ -591,15 +599,18 @@ class LM:
                                 cache["stacks"]):
             moe = spec.kind == "moe"
 
-            def body(x, args, moe=moe):
-                lp, lc = args
-                y, nc = blocks.tblock_paged_decode(
-                    lp, x, lc, cfg, moe=moe, dispatch=self.moe_dispatch,
-                    table=table, lens=lens, pos_pages=pos_pages,
+            def body(carry, args, moe=moe):
+                x, pools = carry
+                lp, layer = args
+                y, pools = blocks.tblock_paged_decode(
+                    lp, x, pools, cfg, moe=moe, dispatch=self.moe_dispatch,
+                    layer=layer, table=table, lens=lens, pos_pages=pos_pages,
                     page_ids=page_ids, offs=offs)
-                return y, nc
+                return (y, pools), None
 
-            x, nc = self._scan_or_loop(body, x, (sp, sc), spec.n)
+            layers = jnp.arange(spec.n, dtype=jnp.int32)
+            (x, nc), _ = self._scan_or_loop(body, (x, sc), (sp, layers),
+                                            spec.n)
             new_stacks.append(nc)
         x = rmsnorm(x, params["final_norm"], eps=cfg.norm_eps)
         return x, dict(cache, len=lens + 1, pos_pages=pos_pages,

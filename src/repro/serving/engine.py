@@ -8,6 +8,9 @@ mid-flight, and preempts-by-eviction when the page pool runs dry. Admission
 prefills the new sequence per-slot (B=1 ``LM.prefill``) and scatters its
 contiguous KV into the sequence's pages host-side, so the hot loop is
 always the SAME compiled step — no recompilation across traffic mixes.
+The cache is donated to that step, which updates the layer-stacked pools
+in place (``LM._paged_decode_hidden``): a step moves the new tokens' K/V
+and the live pages attention reads, not the pool.
 
 Token semantics match ``launch.serve.generate`` exactly: the first emitted
 token comes from the prefill logits, every decode step emits the next, the

@@ -94,6 +94,61 @@ def test_paged_decode_bitwise_matches_contiguous(backend, page, extra, g,
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("layer", [0, 2], ids=["first_layer", "last_layer"])
+@settings(max_examples=5, deadline=None)
+@given(page=st.sampled_from([4, 8, 16]),
+       extra=st.integers(min_value=0, max_value=13),
+       g=st.sampled_from([1, 2, 4]),
+       window=st.sampled_from([None, 48]))
+def test_paged_decode_stacked_pool_matches_sliced_layer(backend, layer, page,
+                                                        extra, g, window):
+    """A layer-stacked pool (L, P, ...) flattened to (L*P, ...) and read
+    through ``kv_table = block_table + layer*P`` gives bitwise the output
+    of the same call on the sliced layer (P, ...): the model's decode step
+    reads each layer's pages where they lie instead of slicing the stack.
+    The XLA reference takes the same addressing."""
+    n_layers, b, hk, d = 3, 2, 2, 32
+    h = hk * g
+    rng = np.random.default_rng(page * 100 + extra * 7 + g + layer)
+    cap = 4 * page
+    kv_len = np.maximum(np.minimum(
+        np.array([cap - extra, 2 * page + 1], np.int32), cap), 1)
+    q = jnp.asarray(rng.standard_normal((b, h, 1, d), np.float32))
+    pools = []
+    for _ in range(n_layers):
+        kc = rng.standard_normal((b, hk, cap, d), np.float32)
+        vc = rng.standard_normal((b, hk, cap, d), np.float32)
+        kp, vp, table = _paged_from_contiguous(np.random.default_rng(0),
+                                               kc, vc, page)
+        pools.append((kp, vp))          # same table (same rng) every layer
+    kst = np.stack([p[0] for p in pools])
+    vst = np.stack([p[1] for p in pools])
+    npages = kst.shape[1]
+    pos = np.full((npages, page), -1, np.int32)
+    for bi in range(b):
+        for j, pg in enumerate(table[bi]):
+            pos[pg] = np.arange(j * page, (j + 1) * page)
+    kw = dict(block_table=table, kv_len=kv_len, pos_pages=pos, window=window)
+    flat = (n_layers * npages, hk, page, d)
+
+    got = np.asarray(paged_decode_attention(
+        q, jnp.asarray(kst.reshape(flat)), jnp.asarray(vst.reshape(flat)),
+        kv_table=table + layer * npages, backend=backend, **kw))
+    exp = np.asarray(paged_decode_attention(
+        q, jnp.asarray(kst[layer]), jnp.asarray(vst[layer]),
+        backend=backend, **kw))
+    assert (got == exp).all(), (
+        f"stacked pool != sliced layer bitwise (layer={layer}, page={page}, "
+        f"g={g}, window={window}, backend={backend})")
+    got_ref = np.asarray(paged_decode_ref(
+        q, jnp.asarray(kst.reshape(flat)), jnp.asarray(vst.reshape(flat)),
+        kv_table=table + layer * npages, **kw))
+    exp_ref = np.asarray(paged_decode_ref(
+        q, jnp.asarray(kst[layer]), jnp.asarray(vst[layer]), **kw))
+    assert (got_ref == exp_ref).all()
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
 def test_paged_decode_matches_ref_with_pos_pages(backend):
     """Rotated layouts: explicit pos_pages (with -1 holes) drive the mask
     identically in the op and the oracle."""

@@ -201,6 +201,62 @@ def test_model_programs_compile(one_chip, chip_defaults, arch):
     assert "ssm_scan" not in train | prefill | decode
 
 
+def test_engine_decode_updates_the_pool_in_place(one_chip, chip_defaults):
+    """The Engine's decode step carries the layer-stacked KV pools through
+    the layer loop and updates them in place: its temporaries stay far
+    below one layer's pool, and no copy, dynamic slice or dynamic update
+    slice in the optimised program has the shape of a layer's pool or of
+    the stack (the per-layer slice, relayouts and restack that cost a
+    decode step several copies of the whole pool)."""
+    import dataclasses
+    import re
+
+    from repro.models import LM
+    from repro.serving import Engine
+
+    n_layers, npages, page = 2, 33, 512
+    cfg = dataclasses.replace(get_config("internlm2_1_8b"), n_layers=n_layers)
+    model = LM(cfg)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    eng = Engine(model, params, batch=B, max_len=4 * page, page_size=page,
+                 num_pages=npages)
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng.cache)
+    toks = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    compiled = eng.decode_step.lower(params, cache, toks).compile()
+
+    hk, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    layer_pool = (npages, hk, page, hd)
+    layer_bytes = 2 * npages * hk * page * hd * 2      # K and V, bf16
+    assert layer_bytes >= 64 * 2 ** 20
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_bytes // 4, (temp, layer_bytes)
+
+    shapes = {",".join(map(str, layer_pool)),
+              ",".join(map(str, (n_layers,) + layer_pool))}
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z-]+)\(", line)
+        if not m:
+            continue
+        name, shape, opcode = m.groups()
+        moves = (opcode in ("copy", "copy-start", "dynamic-slice",
+                            "dynamic-update-slice")
+                 or re.search(r"copy|dynamic-slice|dynamic-update-slice",
+                              name))
+        # a leading unit dim (a one-layer slice of the stack) is the same
+        # pool
+        dims = {re.sub(r"^(1,)+", "", d)
+                for d in re.findall(r"bf16\[([\d,]+)\]", shape)}
+        if moves and shapes & dims:
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    assert "flash_decode_paged" in kernel_names(compiled.as_text())
+
+
 def test_mesh_engine_programs_compile(topology, chip_defaults):
     """The sharded Engine's decode step and prefill on a (1, 4) mesh of
     v5e chips, at internlm2-1.8B widths (one layer). GSPMD cannot partition
