@@ -9,6 +9,7 @@ dry-run (which lowers exactly these functions).
 from __future__ import annotations
 
 import math
+import re
 from functools import partial
 
 import jax
@@ -22,7 +23,7 @@ from repro.parallel.context import Rules, use_rules
 __all__ = [
     "axis_names", "make_shardings", "cache_pspecs", "paged_cache_pspecs",
     "build_train_step", "build_prefill_step", "build_serve_step",
-    "build_paged_serve_step",
+    "build_paged_serve_step", "collective_bytes",
 ]
 
 
@@ -350,3 +351,118 @@ def build_paged_serve_step(model, mesh: Mesh, *, batch: int,
     return jit_fn, {"params": param_sh, "cache": cache_sh, "tokens": tok_sh,
                     "cache_pspecs": c_pspecs, "pspecs": pspecs,
                     "rules": act_rules, "greedy": greedy}
+
+
+# ---------------------------------------------------------------------------
+# what a compiled program's collectives move
+# ---------------------------------------------------------------------------
+
+_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+                "collective-permute")
+_HEADER = re.compile(r"^(ENTRY )?%(\S+) .*\{$")
+_INSTR = re.compile(r"^\s+(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9-]*)\(")
+_ARRAY = re.compile(r"\b(pred|[a-z]+\d+[a-z0-9]*)\[([\d,]*)\]")
+_CALLED = re.compile(
+    r"\b(body|condition|calls|to_apply|called_computations|"
+    r"branch_computations)=(\{[^}]*\}|%[^\s,)]+)")
+
+
+def _arrays(shape_text: str) -> list[int]:
+    """Bytes of each array in an HLO result type (one, or a tuple's)."""
+    out = []
+    for dtype, dims in _ARRAY.findall(shape_text):
+        bits = 8 if dtype == "pred" else int(re.search(r"\d+", dtype).group())
+        out.append(math.prod(int(d) for d in dims.split(",") if d)
+                   * max(bits // 8, 1))
+    return out
+
+
+def _group_size(line: str, n_devices: int) -> int:
+    m = re.search(r"replica_groups=\[\d+,(\d+)\]<=", line)
+    if m:
+        return int(m.group(1))
+    m = re.search(r"replica_groups=\{\{([\d,]*)\}", line)
+    if m:
+        return len(m.group(1).split(","))
+    return n_devices
+
+
+def collective_bytes(compiled_text: str, n_devices: int) -> int:
+    """Bytes that one execution of a compiled SPMD program's collectives
+    bring into one chip from the others, from its optimised HLO text
+    (``Compiled.as_text()``), for groups of ``n`` chips: an all-gather
+    ``(n - 1) / n`` of its output, an all-reduce twice that of its operand
+    (reduce-scatter, then all-gather), a reduce-scatter ``n - 1`` of its
+    output pieces, an all-to-all ``(n - 1) / n`` of its operand and a
+    collective permute its whole buffer. ``-start`` forms count, ``-done``
+    forms do not.
+
+    A collective runs once per execution of the computation that holds it:
+    a loop body's collectives count the loop's trip count (read from the
+    bound its condition compares with). The compiler may split one
+    collective over pieces placed in a loop to overlap it with the loop's
+    work; the pieces share a channel and count once, and only a collective
+    that the program itself issued inside a loop (its ``op_name`` names a
+    ``while/body``) counts the trip count."""
+    comps, order, current = {}, [], None
+    for line in compiled_text.splitlines():
+        m = _HEADER.match(line)
+        if m:
+            current = m.group(2)
+            comps[current] = []
+            order.append((current, bool(m.group(1))))
+        elif current is not None and line.startswith(" "):
+            comps[current].append(line)
+
+    def trips(cond: str) -> int:
+        bounds = [int(c) for line in comps.get(cond, ())
+                  for c in re.findall(r"s32\[\](?:\{[^}]*\})? constant\((\d+)\)",
+                                    line)]
+        return max(bounds, default=1)
+
+    # executions of each computation per execution of the program; callers
+    # are printed after the computations they call, the entry last
+    runs = {name: 0 for name in comps}
+    for name, entry in reversed(order):
+        if entry:
+            runs[name] = 1
+        for line in comps[name]:
+            loop = re.search(r"condition=%([^\s,)]+)", line)
+            for kind, called in _CALLED.findall(line):
+                if kind == "condition":
+                    continue
+                k = trips(loop.group(1)) if kind == "body" and loop else 1
+                for callee in re.findall(r"%([^\s,}]+)", called):
+                    if callee in runs:
+                        runs[callee] += runs[name] * k
+
+    seen, total = set(), 0.0
+    for name, _ in order:
+        for line in comps[name]:
+            m = _INSTR.match(line)
+            if not m or runs[name] == 0:
+                continue
+            shape, op = m.groups()
+            kind = op[:-len("-start")] if op.endswith("-start") else op
+            if kind not in _COLLECTIVES:
+                continue
+            chan = re.search(r"channel_id=(\d+)", line)
+            key = chan.group(1) if chan else (name, line)
+            if key in seen:
+                continue
+            seen.add(key)
+            arrays = _arrays(shape)
+            if op.endswith("-start") and len(arrays) > 1:
+                arrays = ([arrays[-1]] if kind == "all-gather"
+                          else [arrays[1]] if kind == "collective-permute"
+                          else arrays[:len(arrays) // 2])
+            n = _group_size(line, n_devices)
+            size = sum(arrays)
+            per = {"all-gather": size * (n - 1) / n,
+                   "all-reduce": 2 * size * (n - 1) / n,
+                   "reduce-scatter": size * (n - 1),
+                   "all-to-all": size * (n - 1) / n,
+                   "collective-permute": size}[kind]
+            in_loop = "while/body" in line
+            total += per * (runs[name] if in_loop else 1)
+    return int(total)

@@ -26,18 +26,22 @@ engine's process-unique ``engine`` id: ``engine.step`` around the whole
 step; in it ``engine.admit`` per admission (``rid``) with its
 ``engine.prefill`` and ``engine.scatter`` dispatches and the blocking
 ``engine.first_token`` fetch, ``engine.grow`` (growth and preemption),
-``engine.decode`` (the decode dispatch, with ``running``, ``pages_used``
-and ``pages_total``), ``engine.fetch`` (the wait for the next tokens) and
-``engine.emit``. ``engine.queue`` (async, ``rid``) runs from submission,
-or from a preemption, to the start of the admission. Counters:
-``engine.tokens`` here, ``engine.admitted`` / ``retired`` / ``preempted``
-in the scheduler. The jitted programs carry stable names
-(``jit_engine_decode``, ``jit_engine_prefill``, ...).
+``engine.decode`` (the decode dispatch, with ``running``, ``pages_used``,
+``pages_total`` and ``chips``), ``engine.fetch`` (the wait for the next
+tokens) and ``engine.emit``. ``engine.queue`` (async, ``rid``) runs from
+submission, or from a preemption, to the start of the admission. On a mesh,
+``engine.shard`` (``chips``, ``bytes_per_chip``) covers placing the weights
+and the cache at construction. Counters: ``engine.tokens`` and
+``engine.collective_bytes`` (per decode step, what the compiled step's
+collectives bring into one chip; 0 on one chip) here, ``engine.admitted``
+/ ``retired`` / ``preempted`` in the scheduler. The jitted programs carry
+stable names (``jit_engine_decode``, ``jit_engine_prefill``, ...).
 """
 
 from __future__ import annotations
 
 import itertools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +49,7 @@ import numpy as np
 
 from repro.core.device import fit_block
 from repro.parallel.context import use_rules
+from repro.parallel.steps import build_paged_serve_step, collective_bytes
 from repro.runtime import spans
 
 from .scheduler import Scheduler
@@ -52,6 +57,12 @@ from .scheduler import Scheduler
 __all__ = ["Engine"]
 
 _ENGINE_IDS = itertools.count(1)
+
+
+def _placed(a, sharding) -> bool:
+    """Whether ``a`` already lies in ``sharding``."""
+    have = getattr(a, "sharding", None)
+    return have is not None and have.is_equivalent_to(sharding, len(a.shape))
 
 
 class Engine:
@@ -95,32 +106,52 @@ class Engine:
                 f"sequence ({nsp} pages of {self.page_size})")
         self.sched = Scheduler(batch=batch, page_size=self.page_size,
                                num_pages=num_pages, max_len=max_len)
-        self.cache = model.init_paged_cache(batch, num_pages, self.page_size,
-                                            nsp, dtype=cache_dtype)
+
+        def engine_cache():
+            return model.init_paged_cache(batch, num_pages, self.page_size,
+                                          nsp, dtype=cache_dtype)
+
         self._requests = {}
         self._queued = {}                      # rid -> open engine.queue span
         self.last_decode = None
         self._pending = np.zeros((batch,), np.int32)
         self._slot_pages = [[] for _ in range(batch)]
-        rules = None
+        rules = cache_sh = None
+        self.chips = 1 if mesh is None else mesh.size
+        # bytes the decode program's collectives bring into one chip per
+        # step, read from the compiled program at the first decode step
+        self._collective_bytes = None if self.chips > 1 else 0
         if mesh is not None:
-            from repro.parallel.steps import build_paged_serve_step
             mesh_step, specs = build_paged_serve_step(
                 model, mesh, batch=batch, greedy=greedy)
-            self.params = jax.device_put(params, specs["params"])
-            self.cache = jax.device_put(self.cache, specs["cache"])
-            rules = specs["rules"]
+            self._place(params, engine_cache, specs)
+            rules, cache_sh = specs["rules"], specs["cache"]
 
             def engine_decode(p, c, t):
                 return mesh_step(p, c, t)
         else:
             self.params = params
+            self.cache = engine_cache()
             fn = model.paged_greedy_step if greedy else model.paged_decode_step
 
             def engine_decode(p, c, t):
                 return fn(p, t, c)
 
-        self._step_fn = jax.jit(engine_decode, donate_argnums=(1,))
+        def pinned(*names):
+            """On a mesh, the outputs that are cache state keep the cache's
+            own shardings (``None``: left to the compiler). The cache's
+            leaves then enter every program with equal shardings from call
+            to call, and no program traces again inside a serving window."""
+            if cache_sh is None:
+                return {}
+            named = dict(cache_sh, cache=cache_sh, stacks=[
+                {"kp": s["kp"], "vp": s["vp"]} for s in cache_sh["stacks"]])
+            return {"out_shardings": tuple(None if n is None else named[n]
+                                           for n in names)}
+
+        self._step_fn = jax.jit(
+            engine_decode, donate_argnums=(1,),
+            **pinned(*(None,) * (2 if greedy else 1), "cache"))
 
         def engine_prefill(p, t):
             # on a mesh, under the step's rules: the kernels then run per
@@ -164,8 +195,9 @@ class Engine:
             return (out, pos_pages.at[pages].set(rows),
                     table.at[slot].set(pages), lens.at[slot].set(plen))
 
-        self._scatter_fn = jax.jit(engine_scatter,
-                                   donate_argnums=(0, 1, 2, 3))
+        self._scatter_fn = jax.jit(
+            engine_scatter, donate_argnums=(0, 1, 2, 3),
+            **pinned("stacks", "pos_pages", "table", "len"))
 
         # retirement + growth are tiny table/pos edits — still worth one
         # jitted call each instead of an eager dispatch chain
@@ -175,19 +207,48 @@ class Engine:
             return (table.at[slot].set(jnp.zeros((nsp_t,), jnp.int32)),
                     lens.at[slot].set(0))
 
-        self._clear_fn = jax.jit(engine_clear, donate_argnums=(0, 1))
+        self._clear_fn = jax.jit(engine_clear, donate_argnums=(0, 1),
+                                 **pinned("table", "len"))
 
         def engine_grow(pos_pages, table, pages, new, slot):
             cur = pos_pages[pages]                 # (nsp, pg); dup page-0
             rows = jnp.where(new[:, None], -1, cur)  # reads write back as-is
             return pos_pages.at[pages].set(rows), table.at[slot].set(pages)
 
-        self._grow_fn = jax.jit(engine_grow, donate_argnums=(0, 1))
+        self._grow_fn = jax.jit(engine_grow, donate_argnums=(0, 1),
+                                **pinned("pos_pages", "table"))
 
         def engine_first_token(logits):
             return model.greedy_token(logits)
 
         self._greedy_fn = jax.jit(engine_first_token)
+
+    def _place(self, params, engine_cache, specs):
+        """Weights and cache onto the mesh, in the decode step's shardings:
+        parameters that already carry theirs stay where they are, and the
+        cache is made in its shards, so no chip ever holds it whole. Over
+        abstract parameters (``ShapeDtypeStruct``) the cache is abstract
+        too: the programs can then be compiled for a described topology."""
+        make = jax.jit(engine_cache, out_shardings=specs["cache"])
+        shapes = jax.eval_shape(make)
+        per_chip = sum(
+            math.prod(s.shard_shape(a.shape)) * a.dtype.itemsize
+            for a, s in zip(jax.tree.leaves((params, shapes)),
+                            jax.tree.leaves((specs["params"],
+                                             specs["cache"]))))
+        with spans.span("engine.shard", engine=self.engine_id,
+                        chips=self.chips, bytes_per_chip=per_chip):
+            self.params = jax.tree.map(
+                lambda a, s: a if _placed(a, s) else jax.device_put(a, s),
+                params, specs["params"])
+            if any(isinstance(a, jax.ShapeDtypeStruct)
+                   for a in jax.tree.leaves(params)):
+                self.cache = jax.tree.map(
+                    lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                      sharding=s),
+                    shapes, specs["cache"])
+            else:
+                self.cache = make()
 
     # -------------------------------------------------------------- requests
     def submit(self, prompt, max_new: int) -> int:
@@ -323,7 +384,7 @@ class Engine:
         pool = self.sched.pages
         with spans.span("engine.decode", engine=eid, running=len(running),
                         pages_used=pool.num_pages - 1 - pool.free_pages,
-                        pages_total=pool.num_pages - 1):
+                        pages_total=pool.num_pages - 1, chips=self.chips):
             toks = jnp.asarray(self._pending.reshape(-1, 1))
             if self.greedy:
                 nxt, logits, self.cache = self._step_fn(
@@ -331,6 +392,13 @@ class Engine:
             else:
                 logits, self.cache = self._step_fn(self.params, self.cache,
                                                    toks)
+            if self._collective_bytes is None:
+                # the step just compiled: lowering it again finds that
+                # executable in JAX's cache and compiles nothing
+                self._collective_bytes = collective_bytes(
+                    self._step_fn.lower(self.params, self.cache, toks)
+                    .compile().as_text(), self.chips)
+            spans.count("engine.collective_bytes", self._collective_bytes)
         with spans.span("engine.fetch", engine=eid):
             nxt = (np.asarray(nxt) if self.greedy
                    else self._sample(np.asarray(logits)))

@@ -273,3 +273,14 @@ def test_serve_summary_reads_the_counters(capsys, monkeypatch):
     compiled, = [ln for ln in lines if ln.startswith("[serve] compiled: ")]
     assert {"engine_decode", "engine_prefill"} <= \
         set(compiled[len("[serve] compiled: "):].split(", "))
+
+
+def test_one_chip_engine_moves_no_collective_bytes(drained):
+    """On one device the decode span says one chip, no placement span is
+    recorded and the collective counter stays at 0 (the mesh's side:
+    ``tests/test_mesh_shard.py``)."""
+    recs = drained["records"]
+    decodes = [r["attrs"] for r in recs if r["name"] == "engine.decode"]
+    assert decodes and {a["chips"] for a in decodes} == {1}
+    assert not [r for r in recs if r["name"] == "engine.shard"]
+    assert drained["counters"]["engine.collective_bytes"] == 0

@@ -318,3 +318,108 @@ def test_ring_prefill_compiles(topology, chip_defaults):
     text = step.lower(params, batch).compile().as_text()
     assert "ring_flash_fwd" in kernel_names(text)
     assert "collective-permute" in text
+
+
+def _pool_moves(text, shapes):
+    """Lines of a compiled program that gather or copy an array of one of
+    ``shapes`` (dims as "a,b,c", a leading unit dim stripped)."""
+    import re
+
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z-]+)\(", line)
+        if not m:
+            continue
+        name, shape, opcode = m.groups()
+        moves = (opcode in ("all-gather", "all-gather-start", "copy",
+                            "copy-start", "all-to-all", "collective-permute",
+                            "collective-permute-start")
+                 or re.search(r"all-gather|copy", name))
+        dims = {re.sub(r"^(1,)+", "", d)
+                for d in re.findall(r"bf16\[([\d,]+)\]", shape)}
+        if moves and shapes & dims:
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_internlm2_20b_engine_fits_four_chips(topology, chip_defaults):
+    """internlm2-20B at its published widths and depth, served by
+    ``Engine(mesh=)`` on a (1, 4) mesh of v5e chips as the benchmark's
+    ``internlm2-20b-tp4.chat`` cell runs it (24 slots, 97 pages of 512):
+    the engine is built over abstract sharded weights, so its cache is
+    abstract and sharded from the start. Per chip, the decode step's
+    arguments and temporaries fit 15.75 GB, and so do the weights, the
+    pool and the longest prefill's temporaries; the kernels run per
+    shard; no program the engine runs gathers or copies the pool."""
+    import math
+
+    from repro.launch.mesh import make_mesh
+    from repro.models import LM
+    from repro.parallel.steps import make_shardings
+    from repro.serving import Engine
+
+    budget = 15.75e9
+    cfg = get_config("internlm2_20b")
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topology.devices)
+    model = LM(cfg)
+    param_sh = make_shardings(model, mesh)[0]
+    params = _abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                       param_sh)
+    batch, page, npages = 24, 512, 97
+    eng = Engine(model, params, batch=batch, max_len=2048, page_size=page,
+                 num_pages=npages, mesh=mesh)
+    # weights already in the step's shardings are not placed again
+    assert all(a is b for a, b in zip(jax.tree.leaves(eng.params),
+                                      jax.tree.leaves(params)))
+    pool = eng.cache["stacks"][0]["kp"]
+    assert pool.shape == (48, npages, 8, page, 128)
+    assert pool.sharding.shard_shape(pool.shape) == (48, npages, 2, page, 128)
+    whole = ",".join(map(str, pool.shape[1:]))
+    shard = ",".join(map(str, (npages, 2, page, 128)))
+    shapes = {whole, shard, "48," + whole, "48," + shard}
+
+    rep = NamedSharding(mesh, P())
+    toks = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=rep)
+    decode = eng.decode_step.lower(eng.params, eng.cache, toks).compile()
+    mem = decode.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= budget, mem
+    text = decode.as_text()
+    assert {"flash_decode_paged", "lm_head_logits", "rmsnorm"} <= \
+        kernel_names(text)
+    assert not _pool_moves(text, shapes)
+    assert "shard_map" in text                 # kernels run per shard
+
+    def per_chip(a):
+        return math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+
+    weights = sum(map(per_chip, jax.tree.leaves(params)))
+    pools = 2 * per_chip(pool)
+
+    n = 1536                                   # the chat mix's longest prompt
+    prompt = jax.ShapeDtypeStruct((1, n), jnp.int32, sharding=rep)
+    prefill = eng._prefill_fn.lower(eng.params, prompt).compile()
+    ptext = prefill.as_text()
+    assert {"flash_attention_fwd", "rmsnorm", "lm_head_logits"} <= \
+        kernel_names(ptext)
+    assert weights + pools + prefill.memory_analysis().temp_size_in_bytes \
+        <= budget
+    _, pcache = jax.eval_shape(eng._prefill_fn, eng.params, prompt)
+    pcache = _abstract(pcache, prefill.output_shardings[1])
+
+    c = eng.cache
+    nsp = eng.sched.nseq_pages
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
+            for s in ((nsp,), ())]
+    scatter = eng._scatter_fn.lower(c["stacks"], c["pos_pages"], c["table"],
+                                    c["len"], pcache["stacks"], *ints)
+    grow = eng._grow_fn.lower(
+        c["pos_pages"], c["table"], ints[0],
+        jax.ShapeDtypeStruct((nsp,), jnp.bool_, sharding=rep), ints[1])
+    clear = eng._clear_fn.lower(c["table"], c["len"], ints[1])
+    logits = jax.ShapeDtypeStruct((model.vpad,), jnp.float32, sharding=rep)
+    first = eng._greedy_fn.lower(logits)
+    for lowered in (scatter, grow, clear, first):
+        assert not _pool_moves(lowered.compile().as_text(), shapes)
+    out = scatter.compile().output_shardings[0]
+    assert all(s.is_equivalent_to(p.sharding, 5) for s, p in
+               zip(jax.tree.leaves(out), jax.tree.leaves(c["stacks"])))
