@@ -25,6 +25,10 @@ jnp/loops/pallas — the bespoke hand-tiled Pallas era is over:
   dynamic ``kv_len`` input and the slot->absolute-position map a dynamic
   ``slot_pos`` input tile, so one compiled kernel serves every step of an
   incremental-decode loop — including past the wrap of a rolling cache.
+  ``paged_decode_builder`` is its continuous-batching twin over a page
+  pool. Both run one grid cell per (sequence, kv block) that reads the
+  block of every KV head once and serves all their query heads from it
+  (``_decode_body``).
 
 Host paths live in the ``define_op`` declarations in ``ops.py``;
 ``flash_attention_bwd`` below is the backward's host wrapper (kernel builds
@@ -406,9 +410,79 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=None,
 # single-token decode
 # ---------------------------------------------------------------------------
 
+def _decode_cells(b, hk, hkc, nblk):
+    """Grid of the decode kernels, one cell per (sequence, kv block), and
+    ``at(f)``, which turns a map ``f(b_, c, j)`` of (sequence, KV-head
+    chunk, kv block) into an index map over the grid ids. The chunk axis
+    exists only when a cell cannot hold all ``hk`` KV heads (``hkc <
+    hk``)."""
+    if hkc == hk:
+        grid, ids = (b, nblk), (lambda b_, j: (b_, 0, j))
+    else:
+        grid, ids = (b, hk // hkc, nblk), (lambda b_, c, j: (b_, c, j))
+
+    def at(f):
+        return lambda *gids: f(*ids(*gids))
+
+    return grid, at
+
+
+def _decode_scratch(hkc, g, dv):
+    # online-softmax state per query row: m, l, acc
+    return [Scratch((hkc, g, 128), jnp.float32),
+            Scratch((hkc, g, 128), jnp.float32),
+            Scratch((hkc, g, dv), jnp.float32)]
+
+
+def _decode_body(ctx, q_ref, k_ref, v_ref, o_ref, *, run, mask, hkc,
+                 sm_scale):
+    """The online-softmax body both decode kernels share. One cell holds one
+    kv block of ``hkc`` KV heads and serves all their query heads from it:
+    per KV head ``c`` one ``(g, d) x (d, bkv)`` score product and one
+    ``(g, bkv) x (bkv, dv)`` value product, the statistics kept per query
+    row. ``run`` is the cell's whole-block predicate; ``mask()`` gives the
+    block's ``(1, bkv)`` slot mask, shared by every row."""
+    m_scr, l_scr, acc_scr = ctx.scratch
+
+    @ctx.when(ctx.is_first)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @ctx.cell_when(run)
+    def _step():
+        live = mask()
+        for c in range(hkc):
+            q = q_ref[0, c].astype(jnp.float32)          # (g, d)
+            k = k_ref[0, c].astype(jnp.float32)          # (bkv, d)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(live, s, _NEG_INF)
+            m_prev = m_scr[c, :, :1]                     # (g, 1)
+            l_prev = l_scr[c, :, :1]
+            m_cur = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_cur))
+            p = jnp.exp(s - m_cur)
+            p = jnp.where(live, p, 0.0)
+            v = v_ref[0, c].astype(jnp.float32)          # (bkv, dv)
+            acc_scr[c] = acc_scr[c] * corr + lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            l_scr[c, :, :1] = l_prev * corr + p.sum(-1, keepdims=True)
+            m_scr[c, :, :1] = m_cur
+
+    @ctx.when(ctx.is_last)
+    def _fin():
+        for c in range(hkc):
+            l = l_scr[c, :, :1]
+            o_ref[0, c] = (acc_scr[c] /
+                           jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
 def flash_decode_builder(D):
-    """q: (b, h, 1, d) vs cache k: (b, hk, skv, d), v: (b, hk, skv, dv),
-    kv_len: (1, 1) i32, slot_pos: (1, skv) i32 -> o: (b, h, 1, dv).
+    """q: (b, hk, g, d) vs cache k: (b, hk, skv, d), v: (b, hk, skv, dv),
+    kv_len: (1, 1) i32, slot_pos: (1, skv) i32 -> o: (b, hk, g, dv).
 
     Same online-softmax reduce over kv blocks as the forward, with TWO
     dynamic inputs serving one compiled kernel for every step of a decode
@@ -422,29 +496,26 @@ def flash_decode_builder(D):
     (q_pos - slot_pos < window)``. Positional caches pass the identity map
     (the op front-end's default), which recovers the old iota mask exactly.
 
+    Grid ``(b, skv // bkv)``: one cell per (sequence, kv block), holding the
+    block of ``hkc`` KV heads (all of them unless the VMEM budget says
+    otherwise; then the grid is ``(b, hk // hkc, skv // bkv)``) and serving
+    each KV head's ``g`` query heads from it (:func:`_decode_body`). The
+    query and output are the op's ``(b, h, 1, *)`` arrays as ``(b, hk, g,
+    *)`` bitcasts.
+
     The ``kv_len``-driven ``cell_when`` whole-block skip survives for the
     un-wrapped prefix: while ``kv_len <= skv`` a rolling cache has not yet
     rotated (slot == position), so blocks past the query — or fully below
     the window — are skipped without issuing MXU work; once wrapped
     (``kv_len > skv``) every slot may be live and all blocks run."""
-    b, h, hk = D.b, D.h, D.hk
+    b, hk, g, hkc = D.b, D.hk, D.h // D.hk, D.hkc
     skv, d, dv = D.skv, D.d, D.dv
     bkv = D.block_kv
     window = D.window
-    sm_scale = D.sm_scale
-    g = h // hk
     dtype = jnp.dtype(D.dtype)
 
     def body(ctx, q_ref, k_ref, v_ref, len_ref, sp_ref, o_ref):
-        m_scr, l_scr, acc_scr = ctx.scratch
         ki = ctx.reduce_id(0)
-
-        @ctx.when(ctx.is_first)
-        def _init():
-            m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
-            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-
         q_pos = len_ref[0, 0] - 1            # query at the end of the stream
         run = (ki * bkv) <= q_pos
         if window is not None:
@@ -453,64 +524,44 @@ def flash_decode_builder(D):
         # hold live (recent) tokens — the positional skip no longer applies
         run |= q_pos >= skv
 
-        @ctx.cell_when(run)
-        def _step():
+        def mask():
             sp = sp_ref[0]                   # (bkv,) absolute slot positions
-            q = q_ref[0, 0].astype(jnp.float32)          # (1, d)
-            k = k_ref[0, 0].astype(jnp.float32)          # (bkv, d)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-            mask = ((sp >= 0) & (sp <= q_pos))[None, :]  # (1, bkv)
+            live = ((sp >= 0) & (sp <= q_pos))[None, :]  # (1, bkv)
             if window is not None:
-                mask &= ((q_pos - sp) < window)[None, :]
-            s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_scr[:, :1]
-            l_prev = l_scr[:, :1]
-            m_cur = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-            corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_cur))
-            p = jnp.exp(s - m_cur)
-            p = jnp.where(mask, p, 0.0)
-            v = v_ref[0, 0].astype(jnp.float32)
-            acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            l_scr[:, :1] = l_prev * corr + p.sum(-1, keepdims=True)
-            m_scr[:, :1] = m_cur
+                live &= ((q_pos - sp) < window)[None, :]
+            return live
 
-        @ctx.when(ctx.is_last)
-        def _fin():
-            l = l_scr[:, :1]
-            o_ref[0, 0] = (acc_scr[...] /
-                           jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        _decode_body(ctx, q_ref, k_ref, v_ref, o_ref, run=run, mask=mask,
+                     hkc=hkc, sm_scale=D.sm_scale)
 
+    grid, at = _decode_cells(b, hk, hkc, skv // bkv)
     return Spec(
         "flash_decode",
-        grid=(b, h, skv // bkv),
-        reduce_axes=(2,),
-        scratch=[Scratch((1, 128), jnp.float32),   # m
-                 Scratch((1, 128), jnp.float32),   # l
-                 Scratch((1, dv), jnp.float32)],   # acc
+        grid=grid,
+        reduce_axes=(len(grid) - 1,),
+        scratch=_decode_scratch(hkc, g, dv),
         inputs=[
-            Tile("q", (b, h, 1, d), dtype, block=(1, 1, 1, d),
-                 index=lambda b_, h_, ki: (b_, h_, 0, 0)),
-            Tile("k", (b, hk, skv, d), dtype, block=(1, 1, bkv, d),
-                 index=lambda b_, h_, ki: (b_, h_ // g, ki, 0)),
-            Tile("v", (b, hk, skv, dv), dtype, block=(1, 1, bkv, dv),
-                 index=lambda b_, h_, ki: (b_, h_ // g, ki, 0)),
+            Tile("q", (b, hk, g, d), dtype, block=(1, hkc, g, d),
+                 index=at(lambda b_, c, ki: (b_, c, 0, 0))),
+            Tile("k", (b, hk, skv, d), dtype, block=(1, hkc, bkv, d),
+                 index=at(lambda b_, c, ki: (b_, c, ki, 0))),
+            Tile("v", (b, hk, skv, dv), dtype, block=(1, hkc, bkv, dv),
+                 index=at(lambda b_, c, ki: (b_, c, ki, 0))),
             Tile("kv_len", (1, 1), jnp.int32),     # whole-array (dynamic len)
             Tile("slot_pos", (1, skv), jnp.int32,  # slot -> absolute position
-                 block=(1, bkv), index=lambda b_, h_, ki: (0, ki)),
+                 block=(1, bkv), index=at(lambda b_, c, ki: (0, ki))),
         ],
         outputs=[
-            Tile("o", (b, h, 1, dv), dtype, block=(1, 1, 1, dv),
-                 index=lambda b_, h_, ki: (b_, h_, 0, 0)),
+            Tile("o", (b, hk, g, dv), dtype, block=(1, hkc, g, dv),
+                 index=at(lambda b_, c, ki: (b_, c, 0, 0))),
         ],
         body=body)
 
+
 def paged_decode_builder(D):
-    """q: (b, h, 1, d) vs a PAGED cache pool k: (KP, hk, page, d),
+    """q: (b, hk, g, d) vs a PAGED cache pool k: (KP, hk, page, d),
     v: (KP, hk, page, dv), block_table: (b, NP) i32, kv_table: (b, NP) i32,
-    kv_len: (b, 1) i32, pos_pages: (P, 1, page) i32 -> o: (b, h, 1, dv).
+    kv_len: (b, 1) i32, pos_pages: (P, 1, page) i32 -> o: (b, hk, g, dv).
 
     The continuous-batching decode kernel (vLLM's PagedAttention idiom
     through the unified language): each sequence owns a per-slot list of
@@ -524,10 +575,20 @@ def paged_decode_builder(D):
     pages mask identically to the contiguous kernel. ``kv_len`` is
     per-sequence — mixed prompt/generation lengths share one compiled grid.
 
+    Grid ``(b, nsp)``: one cell per (sequence, page). The cell reads the
+    page's K and V for ``hkc`` KV heads in one block — all ``hk`` unless
+    their double-buffered blocks overflow the VMEM budget (``ops.py``'s
+    ``_kv_heads_per_cell``; then the grid is ``(b, hk // hkc, nsp)``) — and
+    serves every query head of those KV heads from it
+    (:func:`_decode_body`), so each live page is read once per call. The
+    query and output are the op's ``(b, h, 1, *)`` arrays as ``(b, hk, g,
+    *)`` bitcasts: query head ``i`` attends KV head ``i // g``.
+
     Bit parity with :func:`flash_decode_builder`: with ``page == block_kv``
     and pages in logical order the online-softmax visits identical blocks in
-    identical order, and fully-masked blocks are exact no-ops — so a paged
-    decode is bitwise the contiguous decode, pages scattered or not.
+    identical order with the same per-KV-head products, and fully-masked
+    blocks are exact no-ops — so a paged decode is bitwise the contiguous
+    decode, pages scattered or not.
 
     The ``cell_when`` whole-block skip is the contiguous kernel's, applied
     per sequence: while un-wrapped (``kv_len <= capacity``) logical page
@@ -540,27 +601,17 @@ def paged_decode_builder(D):
     stack flattened to ``(L*P, ...)`` (a bitcast) with ``kv_table =
     block_table + l*P``: the kernel reads layer ``l``'s pages where they
     lie, while positions, shared by every layer, stay per pool page."""
-    b, h, hk = D.b, D.h, D.hk
+    b, hk, g, hkc = D.b, D.hk, D.h // D.hk, D.hkc
     d, dv = D.d, D.dv
     npages, page, nsp = D.npages, D.page, D.nseq_pages
     kv_pages = D.kv_pages
     window = D.window
-    sm_scale = D.sm_scale
-    g = h // hk
     cap = nsp * page                       # per-sequence slot capacity
     dtype = jnp.dtype(D.dtype)
 
     def body(ctx, q_ref, k_ref, v_ref, tab_ref, kvtab_ref, len_ref, sp_ref,
              o_ref):
-        m_scr, l_scr, acc_scr = ctx.scratch
         j = ctx.reduce_id(0)
-
-        @ctx.when(ctx.is_first)
-        def _init():
-            m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
-            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-
         q_pos = len_ref[0, 0] - 1            # this sequence's query position
         run = (j * page) <= q_pos
         if window is not None:
@@ -569,69 +620,49 @@ def paged_decode_builder(D):
         # hold live (recent) tokens — the positional skip no longer applies
         run |= q_pos >= cap
 
-        @ctx.cell_when(run)
-        def _step():
+        def mask():
             sp = sp_ref[0, 0]                # (page,) absolute slot positions
-            q = q_ref[0, 0].astype(jnp.float32)          # (1, d)
-            k = k_ref[0, 0].astype(jnp.float32)          # (page, d)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-            mask = ((sp >= 0) & (sp <= q_pos))[None, :]  # (1, page)
+            live = ((sp >= 0) & (sp <= q_pos))[None, :]  # (1, page)
             if window is not None:
-                mask &= ((q_pos - sp) < window)[None, :]
-            s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_scr[:, :1]
-            l_prev = l_scr[:, :1]
-            m_cur = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-            corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_cur))
-            p = jnp.exp(s - m_cur)
-            p = jnp.where(mask, p, 0.0)
-            v = v_ref[0, 0].astype(jnp.float32)
-            acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            l_scr[:, :1] = l_prev * corr + p.sum(-1, keepdims=True)
-            m_scr[:, :1] = m_cur
+                live &= ((q_pos - sp) < window)[None, :]
+            return live
 
-        @ctx.when(ctx.is_last)
-        def _fin():
-            l = l_scr[:, :1]
-            o_ref[0, 0] = (acc_scr[...] /
-                           jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        _decode_body(ctx, q_ref, k_ref, v_ref, o_ref, run=run, mask=mask,
+                     hkc=hkc, sm_scale=D.sm_scale)
 
+    grid, at = _decode_cells(b, hk, hkc, nsp)
     return Spec(
         "flash_decode_paged",
-        grid=(b, h, nsp),
-        reduce_axes=(2,),
-        scratch=[Scratch((1, 128), jnp.float32),   # m
-                 Scratch((1, 128), jnp.float32),   # l
-                 Scratch((1, dv), jnp.float32)],   # acc
+        grid=grid,
+        reduce_axes=(len(grid) - 1,),
+        scratch=_decode_scratch(hkc, g, dv),
         inputs=[
-            Tile("q", (b, h, 1, d), dtype, block=(1, 1, 1, d),
-                 index=lambda b_, h_, j: (b_, h_, 0, 0)),
+            Tile("q", (b, hk, g, d), dtype, block=(1, hkc, g, d),
+                 index=at(lambda b_, c, j: (b_, c, 0, 0))),
             # pool page axis: dynamic, read from the K/V table per cell
             # (the static map's 0 there is the ignored placeholder)
-            Tile("k", (kv_pages, hk, page, d), dtype, block=(1, 1, page, d),
-                 index=lambda b_, h_, j: (0, h_ // g, 0, 0),
+            Tile("k", (kv_pages, hk, page, d), dtype,
+                 block=(1, hkc, page, d),
+                 index=at(lambda b_, c, j: (0, c, 0, 0)),
                  index_tile=("kv_table", 0)),
             Tile("v", (kv_pages, hk, page, dv), dtype,
-                 block=(1, 1, page, dv),
-                 index=lambda b_, h_, j: (0, h_ // g, 0, 0),
+                 block=(1, hkc, page, dv),
+                 index=at(lambda b_, c, j: (0, c, 0, 0)),
                  index_tile=("kv_table", 0)),
             Tile("block_table", (b, nsp), jnp.int32, block=(1, 1),
-                 index=lambda b_, h_, j: (b_, j)),
+                 index=at(lambda b_, c, j: (b_, j))),
             Tile("kv_table", (b, nsp), jnp.int32, block=(1, 1),
-                 index=lambda b_, h_, j: (b_, j)),
+                 index=at(lambda b_, c, j: (b_, j))),
             Tile("kv_len", (b, 1), jnp.int32, block=(1, 1),
-                 index=lambda b_, h_, j: (b_, 0)),
+                 index=at(lambda b_, c, j: (b_, 0))),
             # (npages, 1, page): a (1, page) block of the full trailing dims
             Tile("pos_pages", (npages, 1, page), jnp.int32,
-                 block=(1, 1, page), index=lambda b_, h_, j: (0, 0, 0),
+                 block=(1, 1, page), index=at(lambda b_, c, j: (0, 0, 0)),
                  index_tile=("block_table", 0)),
         ],
         outputs=[
-            Tile("o", (b, h, 1, dv), dtype, block=(1, 1, 1, dv),
-                 index=lambda b_, h_, j: (b_, h_, 0, 0)),
+            Tile("o", (b, hk, g, dv), dtype, block=(1, hkc, g, dv),
+                 index=at(lambda b_, c, j: (b_, c, 0, 0))),
         ],
         body=body)
 

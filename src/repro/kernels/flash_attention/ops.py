@@ -8,11 +8,12 @@ front-end's VJP declaration. No O(S^2) residuals are saved — only
 (q, k, v, o, lse); the backward recomputes p blockwise from the lse stats.
 
 ``flash_decode`` is a second declaration for single-token serving: the same
-online-softmax kernel specialized to one query row, with TWO dynamic inputs
-— ``kv_len`` masking the unfilled tail of the cache and ``slot_pos`` mapping
-each cache slot to its absolute position, so rotated rolling-window caches
-run the same kernel (no grad needed at serving time). ``decode_attention``
-is its thin public wrapper.
+online-softmax kernel specialized to one query row per head (a KV head's
+query heads served together from one read of its block), with TWO dynamic
+inputs — ``kv_len`` masking the unfilled tail of the cache and ``slot_pos``
+mapping each cache slot to its absolute position, so rotated rolling-window
+caches run the same kernel (no grad needed at serving time).
+``decode_attention`` is its thin public wrapper.
 
 ``flash_decode_paged`` is the continuous-batching variant: KV lives in a
 POOL of fixed-size pages shared by every sequence, and a per-sequence
@@ -33,9 +34,10 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
-from repro.core import OpVJP, define_op, fit_block
+from repro.core import OpVJP, define_op, fit_block, vmem_budget
 from .kernel import (flash_attention_bwd, flash_decode_builder,
                      flash_fwd_builder, paged_decode_builder)
 from .ref import decode_ref, mha_ref, paged_decode_ref
@@ -184,10 +186,53 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, sm_scale=None,
 # single-token decode
 # ---------------------------------------------------------------------------
 
+def _group_query(q, hk, name):
+    """The decode kernels' query: ``(b, h, 1, d)`` as ``(b, hk, g, d)``, a
+    bitcast putting query head ``i`` in KV head ``i // g``'s group."""
+    b, h, one, d = q.shape
+    if one != 1:
+        raise ValueError(f"{name}: expected a single query token, "
+                         f"got q of shape {q.shape}")
+    if h % hk:
+        raise ValueError(f"{name}: {h} query heads not a multiple of "
+                         f"{hk} kv heads")
+    shape = (b, hk, h // hk, d)
+    if isinstance(q, jax.ShapeDtypeStruct):     # shape probes (warmup, tuning)
+        return jax.ShapeDtypeStruct(shape, q.dtype)
+    return q.reshape(shape)
+
+
+def _ungroup_query(q):
+    b, hk, g, d = q.shape
+    return q.reshape(b, hk * g, 1, d)
+
+
+def _decode_post(outs, args, params):
+    # the kernel's (b, hk, g, dv) output as the op's (b, h, 1, dv)
+    o = outs[0]
+    return o.reshape(args[0].shape[:-1] + o.shape[-1:])
+
+
+def _kv_heads_per_cell(hk, block, d, dv, itemsize):
+    """KV heads per decode grid cell: the most (a divisor of ``hk``) whose
+    K and V blocks of ``block`` slots, double-buffered and with their rows
+    padded to the 128 lanes they take in VMEM, fit 3/4 of the VMEM budget
+    (the rest holds the body's f32 temporaries); one if even a single
+    head's do not fit (the build's VMEM verdict then reports it)."""
+    lanes = -(-d // 128) * 128 + -(-dv // 128) * 128
+    per_head = 2 * block * lanes * itemsize
+    budget = vmem_budget() * 3 // 4
+    for c in range(hk, 0, -1):
+        if hk % c == 0 and c * per_head <= budget:
+            return c
+    return 1
+
+
 def _decode_pre(args, params):
     # read-only on params (.get, never .pop): pre hooks must not eat keys
     # from a dict a caller may reuse across calls
     q, k, v = args
+    q = _group_query(q, k.shape[1], "flash_decode")
     skv = k.shape[2]
     kv_len = params.get("kv_len")
     if kv_len is None:
@@ -204,15 +249,9 @@ def _decode_pre(args, params):
 
 def _decode_defines(args, params):
     q, k, v, kv_len, slot_pos = args
-    b, h, one, d = q.shape
-    if one != 1:
-        raise ValueError(f"flash_decode: expected a single query token, "
-                         f"got q of shape {q.shape}")
-    _, hk, skv, _ = k.shape
+    b, hk, g, d = q.shape
+    skv = k.shape[2]
     dv = v.shape[-1]
-    if h % hk:
-        raise ValueError(f"flash_decode: {h} query heads not a multiple of "
-                         f"{hk} kv heads")
     if q.dtype != k.dtype or q.dtype != v.dtype:
         raise ValueError(f"flash_decode: dtypes disagree "
                          f"({q.dtype}/{k.dtype}/{v.dtype})")
@@ -221,7 +260,8 @@ def _decode_defines(args, params):
                          f"does not match the cache length ({skv} slots)")
     want = params["block_kv"]
     bkv = fit_block(want, skv)
-    ncells = b * h * (skv // bkv)
+    hkc = _kv_heads_per_cell(hk, bkv, d, dv, jnp.dtype(q.dtype).itemsize)
+    ncells = b * (hk // hkc) * (skv // bkv)
     if bkv < min(want, skv) and ncells > 1 << 16:
         raise ValueError(
             f"flash_decode: cache len {skv} degraded block_kv to {bkv} = "
@@ -231,7 +271,7 @@ def _decode_defines(args, params):
         sm_scale = 1.0 / math.sqrt(d)
     window = params["window"]
     return dict(
-        b=b, h=h, hk=hk, skv=skv, d=d, dv=dv, block_kv=bkv,
+        b=b, h=hk * g, hk=hk, hkc=hkc, skv=skv, d=d, dv=dv, block_kv=bkv,
         window=None if window is None else int(window),
         sm_scale=float(sm_scale),
         dtype=jnp.dtype(q.dtype).name)
@@ -245,9 +285,10 @@ def _decode_tune_ref(args, params):
     # every windowed candidate wrong)
     q, k, v, kv_len, slot_pos = args
     n = int(np.asarray(kv_len).reshape(-1)[0])
-    return decode_ref(q, k, v, window=params["window"],
-                      sm_scale=params["sm_scale"], kv_len=n,
-                      slot_pos=jnp.asarray(slot_pos).reshape(-1))
+    o = decode_ref(_ungroup_query(q), k, v, window=params["window"],
+                   sm_scale=params["sm_scale"], kv_len=n,
+                   slot_pos=jnp.asarray(slot_pos).reshape(-1))
+    return o.reshape(q.shape[:-1] + o.shape[-1:])
 
 
 def _decode_example(rng):
@@ -263,6 +304,7 @@ flash_decode = define_op(
     ref=decode_ref,
     derive_defines=_decode_defines,
     pre=_decode_pre,
+    post=_decode_post,
     defaults=dict(window=None, sm_scale=None, block_kv=512),
     array_params=("kv_len", "slot_pos"),    # dynamic length + slot positions
     ref_params=("window", "sm_scale"),
@@ -286,6 +328,7 @@ def _paged_pre(args, params):
     # read-only on params (.get, never .pop) — same contract as _decode_pre
     q, k, v = args
     npages, _, page, _ = k.shape
+    q = _group_query(q, k.shape[1], "flash_decode_paged")
     b = q.shape[0]
     table = params.get("block_table")
     if table is None:
@@ -328,16 +371,10 @@ def _paged_pre(args, params):
 
 def _paged_defines(args, params):
     q, k, v, table, kv_table, kv_len, pos = args
-    b, h, one, d = q.shape
-    if one != 1:
-        raise ValueError(f"flash_decode_paged: expected a single query token, "
-                         f"got q of shape {q.shape}")
-    kv_pages, hk, page, _ = k.shape
+    b, hk, g, d = q.shape
+    kv_pages, _, page, _ = k.shape
     npages = pos.shape[0]
     dv = v.shape[-1]
-    if h % hk:
-        raise ValueError(f"flash_decode_paged: {h} query heads not a multiple "
-                         f"of {hk} kv heads")
     if q.dtype != k.dtype or q.dtype != v.dtype:
         raise ValueError(f"flash_decode_paged: dtypes disagree "
                          f"({q.dtype}/{k.dtype}/{v.dtype})")
@@ -355,19 +392,21 @@ def _paged_defines(args, params):
         sm_scale = 1.0 / math.sqrt(d)
     window = params["window"]
     return dict(
-        b=b, h=h, hk=hk, d=d, dv=dv, npages=npages, kv_pages=kv_pages,
+        b=b, h=hk * g, hk=hk, d=d, dv=dv, npages=npages, kv_pages=kv_pages,
         page=page, nseq_pages=nsp,
+        hkc=_kv_heads_per_cell(hk, page, d, dv, jnp.dtype(q.dtype).itemsize),
         window=None if window is None else int(window),
         sm_scale=float(sm_scale),
         dtype=jnp.dtype(q.dtype).name)
 
 
 def _paged_tune_ref(args, params):
+    # kernel-facing args and output: q (b, hk, g, d) -> o (b, hk, g, dv)
     q, k, v, table, kv_table, kv_len, pos = args
-    return paged_decode_ref(q, k, v, block_table=table, kv_table=kv_table,
-                            kv_len=kv_len, pos_pages=pos,
-                            window=params["window"],
-                            sm_scale=params["sm_scale"])
+    o = paged_decode_ref(_ungroup_query(q), k, v, block_table=table,
+                         kv_table=kv_table, kv_len=kv_len, pos_pages=pos,
+                         window=params["window"], sm_scale=params["sm_scale"])
+    return o.reshape(q.shape[:-1] + o.shape[-1:])
 
 
 def _paged_example(rng):
@@ -386,6 +425,7 @@ flash_decode_paged = define_op(
     ref=paged_decode_ref,
     derive_defines=_paged_defines,
     pre=_paged_pre,
+    post=_decode_post,
     defaults=dict(window=None, sm_scale=None),
     array_params=("block_table", "kv_table", "kv_len", "pos_pages"),
     # the array params ride ref_params too: the oracle needs the table
@@ -398,6 +438,9 @@ flash_decode_paged = define_op(
     POOLS k (P,Hk,page,D) / v (P,Hk,page,Dv), gathered through a per-sequence
     ``block_table`` ((B,n_seq_pages) i32) read by the kernel's index maps at
     runtime (a tile-indexed index map — no contiguous copy on any backend).
+    The grid is (B, n_seq_pages): one cell per (sequence, page) reads the
+    page's K and V for every KV head once (fewer heads per cell only if
+    the VMEM budget cannot hold them) and serves all H query heads.
     ``kv_len`` ((B,) i32) is per-sequence; ``pos_pages`` ((P,page) i32, -1 =
     empty) gives pool slots' absolute positions for rotated-window layouts;
     omitted, logical order is positional. ``kv_table`` ((B,n_seq_pages) i32,

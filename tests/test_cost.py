@@ -56,12 +56,15 @@ def test_matmul_golden_cost():
 
 
 def test_flash_decode_golden_cost():
-    D = dict(b=1, h=4, hk=2, skv=512, d=32, dv=32, block_kv=128,
+    # one cell per (sequence, kv block) holding both KV heads: each K/V
+    # block is fetched once (2 x 4 blocks of 2 x 128 x 32 f32 = 262144 B),
+    # not once per query head
+    D = dict(b=1, h=4, hk=2, hkc=2, skv=512, d=32, dv=32, block_kv=128,
              window=None, sm_scale=float(1 / np.sqrt(32)), dtype="float32")
     rep = estimate_cost(flash_decode_builder(defines_namespace(D)),
                         defines_namespace(D))
-    assert rep.vmem_bytes == 68228
-    assert rep.bytes_in == 532996
+    assert rep.vmem_bytes == 137732
+    assert rep.bytes_in == 264708
     assert rep.bytes_out == 512
     assert rep.flops == 273616
     assert rep.findings == []

@@ -178,6 +178,117 @@ def test_paged_decode_matches_ref_with_pos_pages(backend):
     np.testing.assert_allclose(got, exp, rtol=3e-4, atol=3e-4)
 
 
+def _engine_batch(case, page, nsp, npages, rng):
+    """Block table, kv_len and pos_pages as the engine passes them: page 0
+    is the null page (positions -1) that idle slots (``kv_len`` 1) and
+    pages past a sequence's end point at. ``idle`` puts idle slots beside
+    full and partial sequences; ``window`` masks below a sliding window;
+    ``wrapped`` is a rolling cache past its capacity, slot ``s`` holding
+    the newest position ``t`` with ``t % cap == s``."""
+    cap = nsp * page
+    kv_len = {"idle": [cap, 1, 2 * page + 3, 1],
+              "window": [cap, 3 * page - 2, page + 1, 2],
+              "wrapped": [cap + 5, 3 * cap - 1, 2 * cap, cap + 1]}[case]
+    kv_len = np.array(kv_len, np.int32)
+    table = np.zeros((len(kv_len), nsp), np.int32)
+    pos = np.full((npages, page), -1, np.int32)
+    free = list(rng.permutation(np.arange(1, npages)))
+    for bi, n in enumerate(kv_len):
+        if n == 1:
+            continue                               # idle slot: null page
+        for j in range(min(nsp, -(-int(n) // page))):
+            pg = table[bi, j] = free.pop()
+            slots = np.arange(j * page, (j + 1) * page)
+            if n > cap:                            # rotated: newest per slot
+                pos[pg] = slots + cap * ((n - 1 - slots) // cap)
+            else:
+                pos[pg] = np.where(slots < n, slots, -1)
+    window = {"idle": None, "window": page + 3, "wrapped": cap}[case]
+    return table, kv_len, pos, window
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("case", ["idle", "window", "wrapped"])
+@pytest.mark.parametrize("h,hk", [(16, 8), (12, 2), (8, 8), (8, 1)],
+                         ids=["gqa-g2", "gqa-g6", "mha", "mqa"])
+def test_paged_decode_head_groups_match_ref(backend, case, h, hk):
+    """One grid cell serves every query head of its KV heads: GQA, MHA
+    (g = 1) and MQA (hk = 1) against the oracle, over the engine's batches
+    (idle slots, a sliding window, a wrapped rolling cache)."""
+    b, d, page, nsp = 4, 32, 8, 4
+    npages = b * nsp + 1
+    rng = np.random.default_rng(h * 10 + hk)
+    table, kv_len, pos, window = _engine_batch(case, page, nsp, npages, rng)
+    q = jnp.asarray(rng.standard_normal((b, h, 1, d), np.float32))
+    kp = jnp.asarray(rng.standard_normal((npages, hk, page, d), np.float32))
+    vp = jnp.asarray(rng.standard_normal((npages, hk, page, d), np.float32))
+    kw = dict(block_table=table, kv_len=kv_len, pos_pages=pos, window=window)
+    got = np.asarray(paged_decode_attention(q, kp, vp, backend=backend, **kw))
+    exp = np.asarray(paged_decode_ref(q, kp, vp, **kw))
+    assert got.shape == (b, h, 1, d)
+    np.testing.assert_allclose(got, exp, rtol=3e-4, atol=3e-4)
+    idle = kv_len == 1
+    assert (got[idle] == 0).all()          # the null page attends to nothing
+
+
+def _paged_spec(b, h, hk, page, nsp, d, dtype):
+    from repro.core.lang import defines_namespace
+    from repro.kernels.flash_attention import flash_decode_paged
+
+    npages = b * nsp + 1
+    args = (jax.ShapeDtypeStruct((b, h, 1, d), dtype),
+            jax.ShapeDtypeStruct((npages, hk, page, d), dtype),
+            jax.ShapeDtypeStruct((npages, hk, page, d), dtype))
+    table = np.arange(b * nsp, dtype=np.int32).reshape(b, nsp) + 1
+    _, _, params = flash_decode_paged._resolve(
+        dict(block_table=table, kv_len=np.full((b,), nsp * page, np.int32)))
+    _, defines, _ = flash_decode_paged._prepare(args, params)
+    return flash_decode_paged.builder(defines_namespace(defines))
+
+
+def test_paged_decode_grid_is_one_cell_per_sequence_page(monkeypatch):
+    """At internlm2-1.8B's serving shapes the grid is (b, nsp) and a K/V
+    block spans all 8 KV heads (K and V, 1 MiB each, double-buffered:
+    4 MiB). A VMEM budget too small for that splits the KV heads into
+    chunks of the largest divisor that fits, as a grid axis; the result is
+    unchanged."""
+    b, h, hk, page, nsp, d = 24, 16, 8, 512, 4, 128
+    spec = _paged_spec(b, h, hk, page, nsp, d, jnp.bfloat16)
+    tiles = {t.name: t for t in spec.inputs}
+    assert spec.grid == (b, nsp)
+    assert spec.reduce_axes == (1,)
+    assert tiles["k"].block == (1, hk, page, d)
+    assert tiles["v"].block == (1, hk, page, d)
+    assert tiles["q"].block == (1, hk, h // hk, d)
+
+    # a head's K+V blocks, double-buffered: 512 KiB; 3/4 of 2 MiB holds 3
+    monkeypatch.setenv("REPRO_VMEM_BUDGET", str(2 * 2**20))
+    spec = _paged_spec(b, h, hk, page, nsp, d, jnp.bfloat16)
+    tiles = {t.name: t for t in spec.inputs}
+    assert spec.grid == (b, 4, nsp)        # hkc = 2, the largest divisor
+    assert tiles["k"].block == (1, 2, page, d)
+
+    # a chunked grid computes what the whole-head cell does
+    b, page, d = 4, 16, 128
+    nsp, npages = 4, 4 * 4 + 1
+    rng = np.random.default_rng(0)
+    table, kv_len, pos, window = _engine_batch("idle", page, nsp, npages, rng)
+    q = jnp.asarray(rng.standard_normal((b, h, 1, d), np.float32))
+    kp = jnp.asarray(rng.standard_normal((npages, hk, page, d), np.float32))
+    vp = jnp.asarray(rng.standard_normal((npages, hk, page, d), np.float32))
+    kw = dict(block_table=table, kv_len=kv_len, pos_pages=pos, window=window)
+    # 32 KiB a head here: 3/4 of 128 KiB holds 3, so again hkc = 2
+    monkeypatch.setenv("REPRO_VMEM_BUDGET", str(128 * 2**10))
+    assert _paged_spec(b, h, hk, page, nsp, d, jnp.float32).grid == \
+        (b, 4, nsp)
+    exp = np.asarray(paged_decode_ref(q, kp, vp, **kw))
+    for backend in BACKENDS:
+        got = np.asarray(paged_decode_attention(q, kp, vp, backend=backend,
+                                                **kw))
+        np.testing.assert_allclose(got, exp, rtol=3e-4, atol=3e-4,
+                                   err_msg=backend)
+
+
 # ---------------------------------------------------------------------------
 # allocator / scheduler: no page leaked, none double-owned
 # ---------------------------------------------------------------------------

@@ -82,16 +82,23 @@ def _compile_text(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def test_flash_decode_paged_compiles(one_chip):
-    page, nsp = 128, 16
-    npages = B * nsp + 1
+@pytest.mark.parametrize("h,hk", [(16, 8), (12, 2)],
+                         ids=["internlm2-1.8b", "internlm2-20b-tp4-per-chip"])
+def test_flash_decode_paged_compiles(one_chip, h, hk):
+    """The serving cells' paged decode at their shapes: 24 slots, pages of
+    512, 4 pages a sequence, every KV head of a page in one grid cell."""
+    b, page, nsp = 24, 512, 4
+    npages = b * nsp + 1
     text = _compile_text(
         lambda q, k, v, t, n, p: paged_decode_attention(
             q, k, v, block_table=t, kv_len=n, pos_pages=p, **COMPILED),
-        one_chip, ((B, H, 1, D), BF16), ((npages, HK, page, D), BF16),
-        ((npages, HK, page, D), BF16), ((B, nsp), jnp.int32),
-        ((B,), jnp.int32), ((npages, page), jnp.int32))
-    assert "tpu_custom_call" in text
+        one_chip, ((b, h, 1, D), BF16), ((npages, hk, page, D), BF16),
+        ((npages, hk, page, D), BF16), ((b, nsp), jnp.int32),
+        ((b,), jnp.int32), ((npages, page), jnp.int32))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "flash_decode_paged" in line]
+    assert len(calls) == 1, calls
+    assert kernel_names(text) == {"flash_decode_paged"}
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
